@@ -88,6 +88,21 @@ def _all_shapes_bytes(text: str) -> int:
                for m in _SHAPE_RE.finditer(text))
 
 
+_PALLAS_NAME_RE = re.compile(r'op_name="[^"]*?/(\w+)/pallas_call')
+
+
+def pallas_kernel_names(hlo: str) -> List[str]:
+    """Kernel name of every Pallas launch (``tpu_custom_call``) in compiled
+    TPU HLO text, read from its ``op_name`` metadata (``"?"`` when absent).
+    Each launch is counted once where it is printed, not per loop trip."""
+    names = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _PALLAS_NAME_RE.search(line)
+            names.append(m.group(1) if m else "?")
+    return names
+
+
 def split_computations(hlo: str) -> Tuple[Dict[str, List[str]], str]:
     """computation name -> op lines; plus the entry computation name."""
     comps: Dict[str, List[str]] = {}

@@ -91,13 +91,7 @@ def lenet_target() -> Dict[str, Any]:
     out["step"] = rep.to_json()
 
     apply_of = {"conv": AnalogConv2d.apply, "linear": AnalogLinear.apply}
-    p1, _p2, flat = lenet.feature_sizes(cfg)
-    layer_inputs = {
-        "K1": x,
-        "K2": _sds((LENET_BATCH, p1[0], p1[1], 16)),
-        "W3": _sds((LENET_BATCH, flat)),
-        "W4": _sds((LENET_BATCH,) + _dense_out(params["W3"])),
-    }
+    layer_inputs = _lenet_layer_inputs(cfg, params)
     for layer in lenet.LAYERS:
         state = params[layer]
         fn = apply_of[state.meta.kind]
@@ -111,18 +105,7 @@ def lenet_target() -> Dict[str, Any]:
     # Per-layer vjp: forward read + the fused backward+update — the
     # PR 9 pin is exactly ONE ``bwd_update`` launch per analog layer
     # (no separate transpose read, no pulse-counts launch).
-    for layer in lenet.LAYERS:
-        state = params[layer]
-        fn = apply_of[state.meta.kind]
-        mode = cfg.layer_mode(layer)
-
-        def cycle(s, xv, k, fn=fn, mode=mode):
-            return jnp.sum(fn(s, xv, k, mode=mode) ** 2)
-
-        jax.clear_caches()
-        with ops.launch_label(layer):
-            rep = audit_fn(jax.grad(cycle, argnums=(0, 1), allow_int=True),
-                           state, layer_inputs[layer], _key_struct())
+    for layer, rep in lenet_layer_cycles(cfg, params).items():
         out[f"bwd_update__{layer}"] = rep.to_json()
 
     jax.clear_caches()
@@ -137,6 +120,43 @@ def _dense_out(state) -> tuple:
     m_phys = state.w.shape[0]
     d = state.meta.cfg.devices_per_weight
     return (m_phys // d,)
+
+
+def _lenet_layer_inputs(cfg, params) -> Dict[str, Any]:
+    """Abstract input of each LeNet tile at the audited batch size."""
+    from repro.models import lenet
+    p1, _p2, flat = lenet.feature_sizes(cfg)
+    return {"K1": _sds((LENET_BATCH, 28, 28, 1)),
+            "K2": _sds((LENET_BATCH, p1[0], p1[1], 16)),
+            "W3": _sds((LENET_BATCH, flat)),
+            "W4": _sds((LENET_BATCH,) + _dense_out(params["W3"]))}
+
+
+def lenet_layer_cycles(cfg, params) -> Dict[str, Any]:
+    """Each LeNet tile's forward read + backward/update program, traced
+    abstractly under ``ops.launch_label(layer)``: ``{layer: JaxprReport}``
+    — the kernels each layer's three cycles actually route through."""
+    from repro.analog.modules import AnalogConv2d, AnalogLinear
+    from repro.kernels import ops
+    from repro.models import lenet
+
+    apply_of = {"conv": AnalogConv2d.apply, "linear": AnalogLinear.apply}
+    layer_inputs = _lenet_layer_inputs(cfg, params)
+    reports = {}
+    for layer in lenet.LAYERS:
+        state = params[layer]
+        fn = apply_of[state.meta.kind]
+        mode = cfg.layer_mode(layer)
+
+        def cycle(s, xv, k, fn=fn, mode=mode):
+            return jnp.sum(fn(s, xv, k, mode=mode) ** 2)
+
+        jax.clear_caches()
+        with ops.launch_label(layer):
+            reports[layer] = audit_fn(
+                jax.grad(cycle, argnums=(0, 1), allow_int=True),
+                state, layer_inputs[layer], _key_struct())
+    return reports
 
 
 # ---------------------------------------------------------------------------

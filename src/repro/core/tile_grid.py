@@ -176,19 +176,18 @@ def _block_key(key: Array, flat_index, n_blocks: int) -> Array:
 def _replicated(mesh, *arrays):
     """Pin arrays at a shard_map boundary to an explicit replicated layout.
 
-    Works around a jax 0.4.37 GSPMD miscompilation: a shard_map operand
-    produced under jit by mixing a traced array with broadcasts/slices of
-    mesh-sharded values (the analog bias column concat, ``jnp.tile``
-    replica broadcasts, im2col slice-concats over a previous read's
-    output) reaches the body with elements scaled by the size of mesh
-    axes unmentioned in its in_spec — silently, with ``check_rep`` either
-    way.  Pinning BOTH the operands entering a shard_map and its outputs
-    to the replicated NamedSharding forces clean layouts on each side of
-    the boundary and restores the eager semantics end-to-end (a chained
-    program otherwise re-triggers the bug at the *next* tile's boundary,
-    through the digital glue ops on the sharded output).  The constraint
-    is a no-op for already-replicated values.  (Pinned by the jit parity
-    cases in tests/test_tile_grid.py and the stage-chain case there.)
+    Pinning BOTH the operands entering a shard_map and its outputs to the
+    replicated NamedSharding keeps the partitioner from spreading the
+    digital glue around a grid cycle (the analog bias column concat,
+    ``jnp.tile`` replica broadcasts, im2col slice-concats over a previous
+    read's output) across the mesh.  Left free, it does, and reorders that
+    glue: on jax 0.9 the sharded gradients then differ from the serial
+    oracle by one ulp in a few percent of the elements, which breaks the
+    grid's bitwise sharded == serial contract.  (On jax 0.4.37 the same
+    operands were miscompiled outright — scaled by the size of mesh axes
+    unmentioned in the in_spec.)  The constraint is a no-op for
+    already-replicated values.  (Pinned by the jit parity cases in
+    tests/test_tile_grid.py and the stage-chain case there.)
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
     s = NamedSharding(mesh, P())
@@ -260,7 +259,6 @@ def grid_analog_mvm_sharded(w: Array, x: Array, key: Array, cfg: RPUConfig,
     — one psum per chunk round, with the chunk's noise counters offset so
     the round is bit-identical to the same rows of an unchunked round.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g = grid if grid is not None else TileGrid.for_tile(w.shape, cfg)
@@ -294,8 +292,8 @@ def grid_analog_mvm_sharded(w: Array, x: Array, key: Array, cfg: RPUConfig,
                 P(), P())
     out_specs = (P(*([None] * bdims), out_ax), P(*([None] * bdims)))
     mesh = g.mesh()
-    f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     y, sat = _replicated(mesh, *f(*_replicated(mesh, wp, x, kd, ro)))
     return y[..., :out_dim], sat
 
@@ -564,7 +562,6 @@ def _grid_update_streamed_sharded(wp, mp, src, get_padded, cx, cd, k_a, k_b,
     """Sharded streamed grid update: per-device chunk loops — each device
     generates every chunk from the (replicated) source volume, samples its
     streams, contracts only its block's slices, finalizes once."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     gc, n_blocks = g.grid_cols, g.n_blocks
@@ -600,8 +597,8 @@ def _grid_update_streamed_sharded(wp, mp, src, get_padded, cx, cd, k_a, k_b,
     blockspec = P("array_row", "array_col")
     in_specs = ((blockspec,) * 4 + (P(),) * (5 + n_src))
     mesh = g.mesh()
-    f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=blockspec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=blockspec, check_vma=False)
     (new_w,) = _replicated(mesh, f(*_replicated(
         mesh, wp, mp.dw_up, mp.dw_dn, mp.bound, jnp.asarray(cx),
         jnp.asarray(cd), ka_d, kb_d, kc_d, *src_flat)))
@@ -625,7 +622,6 @@ def _grid_update_reference(wp, mp, rows_s, cols_s, k_c, cfg, g: TileGrid):
 
 
 def _grid_update_sharded(wp, mp, rows_s, cols_s, k_c, cfg, g: TileGrid):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     gc, n_blocks = g.grid_cols, g.n_blocks
@@ -645,8 +641,8 @@ def _grid_update_sharded(wp, mp, rows_s, cols_s, k_c, cfg, g: TileGrid):
                 P(*([None] * bdims), "array_col"),
                 P())
     mesh = g.mesh()
-    f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=blockspec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=blockspec, check_vma=False)
     (new_w,) = _replicated(mesh, f(*_replicated(
         mesh, wp, mp.dw_up, mp.dw_dn, mp.bound, rows_s, cols_s, kd)))
     return new_w

@@ -30,7 +30,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
@@ -104,8 +103,8 @@ def pipeline_apply(block_fn: Callable[[Any, Array], Array],
         outputs = jnp.where(stage_id == n_stages - 1, outputs, 0.0)
         return jax.lax.psum(outputs, axis)
 
-    fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(stage_params, microbatches)
 
 

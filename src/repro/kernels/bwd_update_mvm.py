@@ -41,9 +41,10 @@ update-seed operand (a traced u32 — chunk loops derive it from the loop
 index).
 
 The count matrices live in VMEM scratch for the whole grid
-(``(kp, n_p)`` f32 x2), so eligibility is VMEM-budget-gated
-(``bwd_update_eligible``) and callers fall back to the separate launches
-when a tile is too large — the fallback is the bit-exactness oracle, not a
+(``(kp, n_p)`` f32 x2).  Each wrapper sets its scoped-VMEM limit from its
+block shapes, and the eligibility gates (``bwd_update_eligible``) admit a
+tile only when that working set fits the cap; callers take the separate
+launches for a larger tile — the fallback is the bit-exactness oracle, not a
 different numeric path.
 """
 
@@ -58,18 +59,25 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-from repro.kernels.managed_mvm import (read_segment, replica_cols,
-                                       select_and_average)
+from repro.kernels.conv_mvm import assemble_patch, patch_vmem
+from repro.kernels.managed_mvm import (EPILOGUE_TEMPS, conv_block_dims,
+                                       fits_vmem, pad_to, read_segment,
+                                       replica_cols, select_and_average,
+                                       tile_bytes, vmem_limit)
 from repro.kernels.noisy_mvm import _mix, _uniform24
 
-# Conservative per-launch VMEM budget (bytes) for the eligibility gate;
-# the dominating term is the two full (kp, n_p) count scratches.
-_VMEM_BUDGET = 8 * 1024 * 1024
 
-
-def _pad128(v: int) -> int:
-    return -(-v // 128) * 128
+def _dense_vmem(bm: int, bk: int, kp: int, n_p: int) -> int:
+    """Working-set bytes of one :func:`bwd_update_mvm_pallas` launch; the
+    dominating term is the whole-tile ``(kp, n_p)`` count blocks."""
+    counts = tile_bytes(kp, n_p)
+    row = tile_bytes(bm, n_p)
+    return (2 * (tile_bytes(bm, 1) + tile_bytes(bm, bk)   # nm, delta
+                 + 2 * row + tile_bytes(bk, n_p)          # x, z, w
+                 + tile_bytes(bm, 1) + 2 * counts)        # sat, up, dn
+            + 2 * counts + 3 * row + 2 * tile_bytes(bm, 1)  # scratch
+            + (EPILOGUE_TEMPS + 3) * row                  # read, A streams
+            + 2 * tile_bytes(bk, n_p))                    # per-step counts
 
 
 def bwd_update_eligible(cfg, w_shape: Tuple[int, int],
@@ -77,7 +85,7 @@ def bwd_update_eligible(cfg, w_shape: Tuple[int, int],
     """True when the fused backward+update kernel can take a dense layer's
     backward pass: fusion requested, pallas on, fixed-latency BM, single
     transpose-read segment, no sharded tile grid, counter-offset RNG, and
-    the count scratches + stream working set within the VMEM budget."""
+    the launch's working set within the VMEM cap its wrapper claims."""
     if not (cfg.fuse_bwd_update and cfg.use_pallas and cfg.fast_rng):
         return False
     if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
@@ -88,14 +96,8 @@ def bwd_update_eligible(cfg, w_shape: Tuple[int, int],
     m_phys, n_cols = w_shape
     if m_phys > cfg.max_array_rows:
         return False                      # transpose read would segment
-    kp = -(-m_phys // bk) * bk
-    n_p = _pad128(n_cols)
-    vmem = 4 * (2 * kp * n_p            # net/tot count scratches
-                + 3 * bm * n_p          # seg/acc1/acc2 read scratches
-                + 4 * bm * n_p          # x block + per-slot stream temps
-                + bk * n_p              # w block
-                + 2 * bm * bk)          # delta block + B-stream temp
-    return vmem <= _VMEM_BUDGET
+    return fits_vmem(_dense_vmem(bm, bk, pad_to(m_phys, bk),
+                                 pad_to(n_cols, 128)))
 
 
 def _signed_stream(u, p, sgn):
@@ -172,9 +174,9 @@ def _kernel(rseeds_ref, useeds_ref, gains_ref, nm_ref, d_ref, x_ref, w_ref,
             jnp.abs(b_s), jnp.abs(a_s), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    idx = (pl.dslice(k * bk, bk), slice(None))
-    pl.store(net_ref, idx, pl.load(net_ref, idx) + net)
-    pl.store(tot_ref, idx, pl.load(tot_ref, idx) + tot)
+    idx = (pl.ds(pl.multiple_of(k * bk, bk), bk), slice(None))
+    net_ref[idx] = net_ref[idx] + net
+    tot_ref[idx] = tot_ref[idx] + tot
 
     # --- managed-read epilogue (shared body; n_seg == 1 => one boundary) ----
     @pl.when(k == nk - 1)
@@ -257,9 +259,9 @@ def bwd_update_mvm_pallas(w: jax.Array, d2d: jax.Array, x2d: jax.Array,
     assert d2d.shape[1] == m_phys, (d2d.shape, w.shape)
     assert x2d.shape == (b, n_cols), (x2d.shape, w.shape)
 
-    n_p = _pad128(n_cols)
-    kp = -(-m_phys // bk) * bk
-    bp = -(-b // bm) * bm
+    n_p = pad_to(n_cols, 128)
+    kp = pad_to(m_phys, bk)
+    bp = pad_to(b, bm)
     nb, nk = bp // bm, kp // bk
 
     wpad = jnp.pad(w, ((0, kp - m_phys), (0, n_p - n_cols)))
@@ -307,8 +309,9 @@ def bwd_update_mvm_pallas(w: jax.Array, d2d: jax.Array, x2d: jax.Array,
             pltpu.VMEM((kp, n_p), jnp.float32),    # net coincidence counts
             pltpu.VMEM((kp, n_p), jnp.float32),    # total coincidence counts
         ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(_dense_vmem(bm, bk, kp, n_p), name)),
         interpret=interpret,
     )(read_seeds.reshape(1, 2).astype(jnp.uint32),
       upd_seeds.reshape(1, 3).astype(jnp.uint32),
@@ -325,8 +328,8 @@ def conv_bwd_update_eligible(cfg, geom, w_shape: Tuple[int, int],
                              bk: int = 128) -> bool:
     """True when the fused conv backward+update kernel can take a streamed
     conv layer's backward pass — the conv analogue of
-    :func:`bwd_update_eligible` (per-image patch tile + both count
-    scratches within the VMEM budget)."""
+    :func:`bwd_update_eligible` (per-image working set, patch tile and
+    both count blocks within the VMEM cap its wrapper claims)."""
     if not (cfg.fuse_bwd_update and cfg.use_pallas and cfg.fast_rng):
         return False
     if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
@@ -337,20 +340,25 @@ def conv_bwd_update_eligible(cfg, geom, w_shape: Tuple[int, int],
     m_phys, n_cols = w_shape
     if m_phys > cfg.max_array_rows:
         return False                      # transpose read would segment
-    p_img = geom.oh * geom.ow
-    ppad = -(-p_img // 8) * 8
-    ftm = geom.features + (1 if geom.bias else 0)
-    fp = _pad128(ftm)
-    kp = -(-m_phys // bk) * bk
-    np_c = _pad128(n_cols)
-    vmem = 4 * (geom.h * geom.w * geom.c   # activation volume
-                + ppad * kp                # replicated delta rows
-                + kp * np_c                # weights
-                + 2 * kp * fp              # net/tot count scratches
-                + 3 * ppad * fp            # patch + per-slot A-stream temps
-                + 4 * ppad * np_c          # read working set
-                + 2 * ppad * kp)           # per-slot B-stream temps
-    return vmem <= _VMEM_BUDGET
+    return fits_vmem(_conv_vmem(geom, m_phys, n_cols, bk))
+
+
+def _conv_vmem(geom, m_phys: int, n_cols: int, bk: int) -> int:
+    """Working-set bytes of one :func:`conv_bwd_update_pallas` launch."""
+    ppad, fp = conv_block_dims(geom)
+    kp = pad_to(m_phys, bk)
+    np_c = pad_to(n_cols, 128)
+    counts = tile_bytes(kp, fp)
+    rows_out = tile_bytes(ppad, np_c)
+    rows_in = tile_bytes(ppad, kp)
+    return (2 * (2 * tile_bytes(ppad, 1) + rows_in      # nm, sat, delta
+                 + tile_bytes(geom.h, geom.w, geom.c)   # image
+                 + tile_bytes(kp, np_c)                 # w
+                 + rows_out + 2 * counts)               # z, up, dn
+            + 2 * counts                                # scratch
+            + (EPILOGUE_TEMPS + 1) * rows_out           # read, seg
+            + patch_vmem(geom) + 3 * tile_bytes(ppad, fp)  # A streams
+            + 3 * rows_in + 2 * counts)                 # B streams, counts
 
 
 def _tap_to_channel_perm(geom) -> np.ndarray:
@@ -373,8 +381,6 @@ def _conv_kernel(rseeds_ref, useeds_ref, gains_ref, nm_ref, d_ref, x_ref,
                  m_phys: int, n_cols: int, total: int, bl: int, bk: int,
                  sigma: float, alpha: float, two_phase: bool,
                  retry_scale: float):
-    from repro.kernels.conv_mvm import assemble_patch
-
     i = pl.program_id(0)
     nb = pl.num_programs(0)
 
@@ -503,11 +509,9 @@ def conv_bwd_update_pallas(w: jax.Array, xpad: jax.Array, delta_rep: jax.Array,
     p_img = geom.oh * geom.ow
     total = geom.b * p_img
     assert delta_rep.shape == (total, m_phys), (delta_rep.shape, w.shape)
-    ppad = -(-p_img // 8) * 8
-    ftm = geom.features + (1 if geom.bias else 0)
-    fp = _pad128(ftm)
-    kp = -(-m_phys // bk) * bk
-    np_c = _pad128(n_cols)
+    ppad, fp = conv_block_dims(geom)
+    kp = pad_to(m_phys, bk)
+    np_c = pad_to(n_cols, 128)
 
     wpad = jnp.pad(w, ((0, kp - m_phys), (0, np_c - n_cols)))
     d_pad = jnp.pad(delta_rep.reshape(geom.b, p_img, m_phys),
@@ -553,8 +557,10 @@ def conv_bwd_update_pallas(w: jax.Array, xpad: jax.Array, delta_rep: jax.Array,
             pltpu.VMEM((kp, fp), jnp.float32),     # net coincidence counts
             pltpu.VMEM((kp, fp), jnp.float32),     # total coincidence counts
         ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit(
+                _conv_vmem(geom, m_phys, n_cols, bk), name)),
         interpret=interpret,
     )(read_seeds.reshape(1, 2).astype(jnp.uint32),
       upd_seeds.reshape(1, 2).astype(jnp.uint32),

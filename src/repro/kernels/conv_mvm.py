@@ -24,9 +24,10 @@ tap-major rows (``t * C + c``) so each tap's slice lands contiguously in
 the on-chip patch tile.  The bias column becomes the last tap-major row
 with a constant-1 patch column.  The whole (replica-padded) physical output
 dim lives in one block, like ``managed_mvm``; one image's positions form
-the row block.  VMEM needs ``O(OH*OW * (C kh kw + out_phys))`` floats —
-``conv_kernel_eligible`` gates on a budget and falls back to the
-gather + ``managed_mvm`` path (bit-compatible counters) when it won't fit.
+the row block.  VMEM needs ``O(OH*OW * (C kh kw + out_phys))`` floats; the
+wrapper sets its scoped-VMEM limit from the block shapes, and
+``conv_kernel_eligible`` sends a layer whose working set exceeds the cap to
+the gather + ``managed_mvm`` path (bit-compatible counters).
 """
 
 from __future__ import annotations
@@ -38,20 +39,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-from repro.kernels.managed_mvm import (read_segment, replica_cols,
-                                       select_and_average)
+from repro.kernels.managed_mvm import (EPILOGUE_TEMPS, conv_block_dims,
+                                       fits_vmem, pad_to, read_segment,
+                                       replica_cols, select_and_average,
+                                       tile_bytes, vmem_limit)
 
-# Conservative per-step VMEM budget for eligibility (bytes; TPU cores have
-# ~16 MB — leave headroom for double buffering and the compiler).
-_VMEM_BUDGET = 8 * 1024 * 1024
+
+def _conv_read_vmem(geom, d_avg: int, out_f: int) -> int:
+    """Working-set bytes of one :func:`conv_managed_mvm_pallas` launch."""
+    ppad, fp = conv_block_dims(geom)
+    out_f_p = pad_to(out_f, 128)
+    rows = tile_bytes(ppad, d_avg * out_f_p)
+    return (2 * (tile_bytes(ppad, 1) + tile_bytes(geom.h, geom.w, geom.c)
+                 + tile_bytes(fp, d_avg * out_f_p)      # nm, image, w
+                 + tile_bytes(ppad, out_f_p) + tile_bytes(ppad, 1))  # y, sat
+            + patch_vmem(geom) + (EPILOGUE_TEMPS + 1) * rows)
+
+
+def patch_vmem(geom) -> int:
+    """Bytes of the on-chip patch assembly (:func:`assemble_patch`): the
+    ``kh*kw`` tap slices and the padded patch tile."""
+    ppad, fp = conv_block_dims(geom)
+    return (geom.kh * geom.kw * tile_bytes(geom.oh * geom.ow, geom.c)
+            + tile_bytes(ppad, fp))
 
 
 def conv_kernel_eligible(cfg, geom, w_shape: Tuple[int, int]) -> bool:
     """True when the implicit-im2col kernel can take the conv forward:
     pallas on, fixed-latency BM (off / two-phase), a single physical
-    contraction segment, and the per-image working set within budget."""
+    contraction segment, and the per-image working set within the VMEM
+    cap the wrapper claims."""
     if not cfg.use_pallas:
         return False
     if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
@@ -61,16 +80,8 @@ def conv_kernel_eligible(cfg, geom, w_shape: Tuple[int, int]) -> bool:
         return False                      # iterative BM is multi-launch
     if geom.cols > cfg.max_array_cols:
         return False                      # would need contraction segments
-    p_img = geom.oh * geom.ow
-    ppad = -(-p_img // 8) * 8
-    ftm = geom.features + (1 if geom.bias else 0)
-    fp = -(-ftm // 128) * 128
-    out_f = w_shape[0] // cfg.devices_per_weight
-    out_f_p = -(-out_f // 128) * 128
-    outp = cfg.devices_per_weight * out_f_p
-    vmem = 4 * (geom.h * geom.w * geom.c + ppad * fp + fp * outp
-                + 4 * ppad * outp)
-    return vmem <= _VMEM_BUDGET
+    d_avg = cfg.devices_per_weight
+    return fits_vmem(_conv_read_vmem(geom, d_avg, w_shape[0] // d_avg))
 
 
 def assemble_patch(xb, geom, p_img: int, ppad: int, fp: int):
@@ -143,7 +154,7 @@ def tap_major_weights(w: jax.Array, geom, d_avg: int, out_f_p: int
     if geom.bias:
         w_tm = jnp.concatenate([w_tm, w[:, geom.features:].T], axis=0)
     ftm = w_tm.shape[0]
-    fp = -(-ftm // 128) * 128
+    fp = pad_to(ftm, 128)
     out_f = m // d_avg
     w_tm = w_tm.reshape(ftm, d_avg, out_f)
     w_tm = jnp.pad(w_tm, ((0, fp - ftm), (0, 0), (0, out_f_p - out_f)))
@@ -178,10 +189,9 @@ def conv_managed_mvm_pallas(w: jax.Array, xpad: jax.Array, nm_s: jax.Array,
     out_f = m // d_avg
     p_img = geom.oh * geom.ow
     total = geom.b * p_img
-    ppad = -(-p_img // 8) * 8
+    ppad, fp = conv_block_dims(geom)
     ftm = geom.features + (1 if geom.bias else 0)
-    fp = -(-ftm // 128) * 128
-    out_f_p = -(-out_f // 128) * 128
+    out_f_p = pad_to(out_f, 128)
     outp = d_avg * out_f_p
 
     w_tm = tap_major_weights(w, geom, d_avg, out_f_p)
@@ -214,8 +224,10 @@ def conv_managed_mvm_pallas(w: jax.Array, xpad: jax.Array, nm_s: jax.Array,
             jax.ShapeDtypeStruct((geom.b * ppad, out_f_p), xpad.dtype),
             jax.ShapeDtypeStruct((geom.b * ppad, 1), jnp.int32),
         ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit(
+                _conv_read_vmem(geom, d_avg, out_f), name)),
         interpret=interpret,
     )(seeds.reshape(1, 2).astype(jnp.uint32), nm_pad, xpad, w_tm)
     y = y.reshape(geom.b, ppad, out_f_p)[:, :p_img, :out_f]

@@ -29,13 +29,17 @@ Layout: grid ``(batch/bm, K/bk)`` with the contraction axis innermost
 one VMEM block so the per-vector saturation flag — which gates the select
 across *all* output channels — never leaves the chip.  Weights are padded
 per replica block to a lane multiple so the in-kernel #_d average is a few
-static slices.  The iterative-BM while_loop is inherently multi-launch
-(data-dependent retry count) and keeps using ``noisy_mvm`` per read.
+static slices.  The scoped-VMEM limit is set from those block shapes
+(:func:`managed_read_vmem`); an output too wide for one core's VMEM (a
+100k-row unembed) raises rather than compiles.  The iterative-BM
+while_loop is inherently multi-launch (data-dependent retry count) and
+keeps using ``noisy_mvm`` per read.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -44,9 +48,77 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.kernels.noisy_mvm import _mix, _normal_at
+
+
+# ---------------------------------------------------------------------------
+# VMEM sizing
+#
+# The kernels that hold a whole output (or count) dimension in one block
+# compute their scoped-VMEM limit from their block shapes: the pipelined
+# operand blocks (two buffers each), the scratch, and the live elementwise
+# temporaries of the read epilogue, each rounded up to the (8, 128) tile
+# Mosaic lays a 32-bit array out in.  The same estimate gates eligibility;
+# ``tests/test_tpu_compile.py`` compiles each gate's largest admitted shape
+# for v5e and pins those maxima, so a widened estimate needs a new case.
+# ---------------------------------------------------------------------------
+
+#: VMEM one launch may claim: a TPU v5e TensorCore has 128 MiB; the rest is
+#: headroom for Mosaic's internal scratch.
+VMEM_CAP = 100 * 2 ** 20
+#: Mosaic's default scoped-VMEM limit on v5e — never ask for less.
+_SCOPED_DEFAULT = 16 * 2 ** 20
+#: live (rows, out) f32 temporaries of the noise + select epilogue (measured
+#: by compiling for v5e: about three at 4096 and 11008 output columns)
+EPILOGUE_TEMPS = 4
+_SLACK = 2 * 2 ** 20
+
+
+def pad_to(v: int, m: int) -> int:
+    """``v`` rounded up to a multiple of ``m``."""
+    return -(-v // m) * m
+
+
+def tile_bytes(*shape: int) -> int:
+    """Bytes of one 32-bit VMEM array of ``shape`` in (8, 128) tiles."""
+    *lead, r, c = shape
+    return 4 * math.prod(lead) * pad_to(r, 8) * pad_to(c, 128)
+
+
+def conv_block_dims(geom) -> Tuple[int, int]:
+    """``(ppad, fp)``: one image's output positions padded to a sublane
+    multiple and its patch features (bias column included) padded to a
+    lane multiple — the block layout of both implicit-im2col kernels."""
+    return (pad_to(geom.oh * geom.ow, 8),
+            pad_to(geom.features + (1 if geom.bias else 0), 128))
+
+
+def fits_vmem(nbytes: int) -> bool:
+    """Whether a launch with working set ``nbytes`` fits :data:`VMEM_CAP`
+    (the eligibility gates' test)."""
+    return nbytes + _SLACK <= VMEM_CAP
+
+
+def vmem_limit(nbytes: int, name: str) -> int:
+    """The scoped-VMEM limit for a launch whose working set is ``nbytes``.
+
+    Raises when the working set does not fit :data:`VMEM_CAP`: a shape
+    that cannot fit one core's VMEM fails here, by name, instead of in the
+    compiler or on another path."""
+    if not fits_vmem(nbytes):
+        raise ValueError(
+            f"{name}: block working set {nbytes / 2 ** 20:.1f} MiB exceeds "
+            f"the {VMEM_CAP >> 20} MiB of VMEM a launch may claim")
+    return max(nbytes + _SLACK, _SCOPED_DEFAULT)
+
+
+def managed_read_vmem(bm: int, bk: int, outp: int, out_f_p: int) -> int:
+    """Working-set bytes of one :func:`managed_mvm_pallas` launch."""
+    return (2 * (tile_bytes(bm, bk) + tile_bytes(outp, bk)      # x, w
+                 + tile_bytes(bm, 1)                            # nm scale
+                 + tile_bytes(bm, out_f_p) + tile_bytes(bm, 1))  # y, sat
+            + 3 * tile_bytes(bm, outp) + 2 * tile_bytes(bm, 1)  # scratch
+            + EPILOGUE_TEMPS * tile_bytes(bm, outp))
 
 
 # ---------------------------------------------------------------------------
@@ -229,12 +301,12 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
     rowoff = (jnp.zeros((), jnp.uint32) if row_offset is None
               else jnp.asarray(row_offset, jnp.uint32))
 
-    out_f_p = -(-out_f // 128) * 128          # per-replica lane-padded width
+    out_f_p = pad_to(out_f, 128)              # per-replica lane-padded width
     outp = d_avg * out_f_p
     seg_len = -(-k_dim // n_seg)
-    seg_len_p = -(-seg_len // bk) * bk
+    seg_len_p = pad_to(seg_len, bk)
     kp = n_seg * seg_len_p
-    bp = -(-b // bm) * bm
+    bp = pad_to(b, bm)
 
     def pad_contraction(a, axis):
         pad_tail = [(0, 0)] * a.ndim
@@ -307,8 +379,10 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
             pltpu.VMEM((bm, 1), jnp.int32),        # read-1 saturation
             pltpu.VMEM((bm, 1), jnp.int32),        # read-2 saturation
         ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(
+                managed_read_vmem(bm, bk, outp, out_f_p), name)),
         interpret=interpret,
     )(seeds.reshape(1, 2).astype(jnp.uint32), rowoff.reshape(1, 1), nm_pad,
       xpad, wpad)

@@ -18,7 +18,10 @@ Tiling: ``(bm, bn, bk) = (128, 128, 128)`` MXU-aligned blocks; grid =
 accumulators revisited across k.
 
 The saturation flag needed by bound management is emitted as a per
-(row-block, out-block) int32 map, OR-reduced by the ``ops.py`` wrapper.
+(row-block, out-block) int32 map, OR-reduced by the ``ops.py`` wrapper.  The
+kernel writes it lane-dense — one ``(bm, 128)`` block per out-block, the flag
+broadcast across the lanes — because a ``(bm, 1)`` block of a wider array is
+not aligned to the TPU tiling; the wrapper keeps lane 0.
 
 Bit-exactness: with the same key, this kernel and
 ``repro.core.tile.analog_mvm_reference`` draw *identical* noise (same
@@ -36,8 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
+_LANES = 128                 # TPU lane width (last-dim tile)
 _GOLDEN = np.uint32(0x9E3779B9)
 _M1 = np.uint32(0x21F0AAAD)
 _M2 = np.uint32(0x735A2D97)
@@ -51,7 +53,10 @@ def _mix(x):
 
 
 def _uniform24(bits):
-    return (bits >> 8).astype(jnp.float32) * np.float32(1.0 / (1 << 24))
+    # via int32: Mosaic has no uint32 -> float32 cast, and ``bits >> 8`` is
+    # below 2^24, so the detour is exact (bit-identical to fastrng.uniform)
+    return ((bits >> 8).astype(jnp.int32).astype(jnp.float32)
+            * np.float32(1.0 / (1 << 24)))
 
 
 def _normal_at(seed_mixed, e, n_total):
@@ -117,7 +122,7 @@ def _kernel(seed_ref, off_ref, x_ref, w_ref, y_ref, sat_ref, seg_ref,
     @pl.when(k == nk - 1)
     def _finalize():
         y_ref[...] = acc_ref[...].astype(y_ref.dtype)
-        sat_ref[...] = satacc_ref[...]
+        sat_ref[...] = jnp.broadcast_to(satacc_ref[...], sat_ref.shape)
 
 
 @functools.partial(
@@ -206,20 +211,20 @@ def noisy_mvm_pallas(w: jax.Array, x2d: jax.Array, seed: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),     # y
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, j)),      # sat
+            pl.BlockSpec((bm, _LANES), lambda i, j, k: (i, j)),  # sat
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bp, outp), x2d.dtype),
-            jax.ShapeDtypeStruct((bp, no), jnp.int32),
+            jax.ShapeDtypeStruct((bp, no * _LANES), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),   # segment accumulator
             pltpu.VMEM((bm, bn), jnp.float32),   # output accumulator
             pltpu.VMEM((bm, 1), jnp.int32),      # saturation accumulator
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed.reshape(1, 1).astype(jnp.uint32), rowoff.reshape(1, 1), xpad,
       wpad)
-    return y[:b, :out_dim], sat[:b]
+    return y[:b, :out_dim], sat.reshape(bp, no, _LANES)[:b, :, 0]

@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt the (config-carrying, arbitrary-batch-shape) tile API onto the
-2-D padded kernel interfaces, pick interpret mode automatically on CPU
-(the kernels execute in Python for correctness validation; TPU is the
-performance target), and fall back to the pure-jnp reference when a shape is
-too tiny to be worth launching a kernel for.
+2-D padded kernel interfaces and pick interpret mode automatically off the
+TPU (the kernels then execute in Python for correctness validation; the TPU
+is the target).  Every shape launches its kernel: there is no fallback to
+the pure-jnp reference, and a shape whose blocks cannot fit the TPU's VMEM
+raises (``managed_mvm.vmem_limit``).
 """
 
 from __future__ import annotations

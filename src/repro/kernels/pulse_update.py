@@ -29,8 +29,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.kernels.noisy_mvm import _mix, _normal_at
 
 
@@ -153,7 +151,7 @@ def pulse_counts_pallas(streams_rows: jax.Array, streams_cols: jax.Array, *,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rp, cp)
@@ -207,7 +205,7 @@ def pulse_update_pallas(w: jax.Array, dw_up: jax.Array, dw_dn: jax.Array,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed.reshape(1, 1).astype(jnp.uint32), rp, cp, wp, upp, dnp, bp)

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.serve import engine
+from repro.utils.compile_cache import use_compile_cache
 
 
 def _print_policy_table(params) -> None:
@@ -139,6 +140,7 @@ def serve_continuous(arch: str, *, slots: int, n_requests: int,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)

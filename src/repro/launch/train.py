@@ -42,6 +42,7 @@ from repro.distributed.fault import (DeviceLossError, FaultInjector,
                                      PreemptionHandler, StragglerWatchdog)
 from repro.train import engine as engine_lib
 from repro.train import lm
+from repro.utils.compile_cache import use_compile_cache
 
 
 def build_mesh_and_rules(smoke: bool, multi_pod: bool):
@@ -458,6 +459,7 @@ class _null:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

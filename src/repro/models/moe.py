@@ -153,7 +153,6 @@ def _apply_a2a(p, x: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
     Requires n_experts %% model_axis == 0 (kimi: 384/16); callers fall back
     to the gather path otherwise (mixtral's 8 experts on a 16-way axis).
     """
-    import jax.experimental.shard_map as jsm
     from repro.distributed import sharding as shd
 
     mo = cfg.moe
@@ -235,8 +234,8 @@ def _apply_a2a(p, x: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
     in_specs = (data_spec, P(None, None), P("model", None, None),
                 P("model", None, None), P("model", None, None))
     out_specs = (P(batch_axes + ("model",), None), P())
-    fn = jsm.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     yf, aux = fn(xf, p["router"], p["wi"], p["wg"], p["wo"])
     # undo the per-data-shard padding to a model-axis multiple
     n_data = 1

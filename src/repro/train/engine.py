@@ -88,7 +88,6 @@ def data_parallel_grads(grads_fn: Callable) -> Callable:
     The trailing arg must be the PRNG key; it is folded with the shard
     index so analog noise decorrelates across shards.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -110,8 +109,8 @@ def data_parallel_grads(grads_fn: Callable) -> Callable:
                 else t, g)
 
         in_specs = (P(), P()) + (P("data"),) * len(batched)
-        f = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=P(), check_vma=False)
         return f(params, kd, *batched)
 
     return wrapped

@@ -31,7 +31,9 @@ AUDIT = os.path.join(REPO, "scripts", "audit.py")
 
 
 def _run_audit(args, timeout=900):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child is CPU-only: a parent holding a TPU would starve it
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)        # the CLI forces its own device count
     return subprocess.run([sys.executable, AUDIT, *args],
                           capture_output=True, text=True, timeout=timeout,

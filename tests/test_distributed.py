@@ -55,8 +55,9 @@ def _run_sub(body: str, devices: int = 8) -> str:
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("SUBPROCESS_OK")
     """)
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(REPO, "src"))
+    # the child is CPU-only: a parent holding a TPU would starve it
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=900, env=env)
     assert res.returncode == 0, res.stderr[-4000:]

@@ -57,7 +57,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(body: str, *, env=None, devices=None, expect_sigkill=False,
          timeout=900):
     code = textwrap.dedent(body)
-    e = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child is CPU-only: a parent holding a TPU would starve it
+    e = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+             JAX_PLATFORMS="cpu")
     # never inherit fault-injection config from an outer harness
     for k in ("REPRO_FAULT_MODE", "REPRO_FAULT_STEP", "REPRO_FAULT_DROP",
               "REPRO_CKPT_WRITE_DELAY"):

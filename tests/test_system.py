@@ -120,3 +120,27 @@ def test_analog_lm_train_step_runs():
     w_old = params["layers"]["mlp"]["wi"]["w"]
     w_new = p2["layers"]["mlp"]["wi"]["w"]
     assert float(jnp.max(jnp.abs(w_new - w_old))) > 0.0
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache/dir"])
+def test_compile_cache_location(monkeypatch, env_dir):
+    """Entry points keep JAX's compile cache where the environment says,
+    else at the fixed ``<repo>/.jax_cache`` — never at a moving path."""
+    import os
+    from repro.utils import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        path = compile_cache.use_compile_cache()
+        if env_dir is None:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        else:
+            assert path == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
