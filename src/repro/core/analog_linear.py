@@ -51,16 +51,19 @@ def _split3(key: Array):
     return jax.random.split(key, 3)
 
 
+@jax.named_scope("forward")
 def _fwd_read(cfg: RPUConfig, w: Array, x: Array, key: Array) -> Array:
     state = TileState(w=w, maps=None, seed=key)  # maps unused in reads
     return tile_lib.tile_forward(state, x, key, cfg)
 
 
+@jax.named_scope("backward")
 def _bwd_read(cfg: RPUConfig, w: Array, g: Array, key: Array) -> Array:
     state = TileState(w=w, maps=None, seed=key)
     return tile_lib.tile_backward(state, g, key, cfg)
 
 
+@jax.named_scope("update")
 def _pulse_w_bar(cfg, w, maps, x, g, key, lr):
     """w_bar such that ``w - w_bar == clip(w + DW_pulse(x, -g))``."""
     new_w = update_lib.pulse_update(w, maps, x, -g, key, cfg, lr)
@@ -75,6 +78,7 @@ def _fuse_eligible(cfg: RPUConfig, w: Array) -> bool:
     return bwd_update_eligible(cfg, w.shape)
 
 
+@jax.named_scope("backward_update")
 def _fused_bwd(cfg, w, maps, x, g, k_b, k_u, lr):
     """Backward + update cycles in one Pallas launch — bit-identical to
     ``_bwd_read`` + ``_pulse_w_bar`` (the separate-launch oracle)."""

@@ -259,6 +259,7 @@ def _position_indices(geom: ConvGeom, start, chunk: int):
     return b_idx, r // geom.ow, r % geom.ow, valid
 
 
+@jax.named_scope("im2col")
 def gather_columns(xpad: Array, geom: ConvGeom, start, chunk: int) -> Array:
     """Materialize one chunk of im2col columns ``(chunk, cols)`` from the
     padded activation volume (channel-major feature order, bias ones
@@ -287,6 +288,7 @@ def window_absmax(xpad: Array, geom: ConvGeom) -> Array:
     return m
 
 
+@jax.named_scope("col2im")
 def col2im_add(z: Array, geom: ConvGeom, start, chunk: int,
                xbar: Array) -> Array:
     """Scatter-add one chunk's transpose-read columns ``(chunk, features)``
@@ -332,6 +334,7 @@ def _conv_nm_scale(xpad: Array, geom: ConvGeom) -> Array:
     return jnp.where(s > management._EPS, s, 1.0)
 
 
+@jax.named_scope("forward")
 def _stream_forward(cfg: RPUConfig, geom: ConvGeom, w: Array, x: Array,
                     k_f: Array) -> Array:
     """Forward cycle: managed reads over position-column chunks."""
@@ -363,6 +366,7 @@ def _stream_forward(cfg: RPUConfig, geom: ConvGeom, w: Array, x: Array,
     return y[:total].reshape(geom.b, geom.oh, geom.ow, out_f)
 
 
+@jax.named_scope("backward")
 def _stream_backward(cfg: RPUConfig, geom: ConvGeom, w: Array, g: Array,
                      k_b: Array) -> Array:
     """Backward cycle: transpose-read chunks + deterministic col2im."""
@@ -389,6 +393,7 @@ def _stream_backward(cfg: RPUConfig, geom: ConvGeom, w: Array, g: Array,
                          (geom.b, pt + hp, pl + wp, geom.c))
 
 
+@jax.named_scope("update")
 def _stream_pulse_w_bar(cfg: RPUConfig, geom: ConvGeom, w, maps, x, g, k_u,
                         lr) -> Array:
     """Update cycle: streamed pulse update over (column, error) chunks;
@@ -437,6 +442,7 @@ def _conv_fuse_eligible(cfg: RPUConfig, geom: ConvGeom, w: Array) -> bool:
     return conv_bwd_update_eligible(cfg, geom, w.shape)
 
 
+@jax.named_scope("backward_update")
 def _fused_bwd_update(cfg: RPUConfig, geom: ConvGeom, w, maps, x, g, k_b,
                       k_u, lr) -> Tuple[Array, Array]:
     """Backward + update cycles in ONE Pallas launch

@@ -183,21 +183,28 @@ def apply(params: Dict[str, AnalogState], images: Array,
     # apply-time config/padding overrides keep post-init retrofits
     # (with_stream_chunks on an existing run) and the legacy semantics
     # where the LeNetConfig, not the state, is the source of truth.
-    h = AnalogConv2d.apply(params["K1"], images, ks[0], lr=lr,
-                           mode=cfg.layer_mode("K1"), cfg=cfg.cfg("K1"),
-                           padding=cfg.conv_padding)
+    # Each tile runs under a name scope of its layer key, so device ops
+    # read ``K2/backward/col2im/...`` in compiled HLO and profiler traces
+    # (the cycle and conv-mapping scopes live in core/).
+    with jax.named_scope("K1"):
+        h = AnalogConv2d.apply(params["K1"], images, ks[0], lr=lr,
+                               mode=cfg.layer_mode("K1"), cfg=cfg.cfg("K1"),
+                               padding=cfg.conv_padding)
     h = _maxpool2(jnp.tanh(h))                       # (B, 12, 12, 16)
-    h = AnalogConv2d.apply(params["K2"], h, ks[1], lr=lr,
-                           mode=cfg.layer_mode("K2"), cfg=cfg.cfg("K2"),
-                           padding=cfg.conv_padding)
+    with jax.named_scope("K2"):
+        h = AnalogConv2d.apply(params["K2"], h, ks[1], lr=lr,
+                               mode=cfg.layer_mode("K2"), cfg=cfg.cfg("K2"),
+                               padding=cfg.conv_padding)
     h = _maxpool2(jnp.tanh(h))                       # (B, 4, 4, 32)
     h = h.reshape(h.shape[0], -1)                    # (B, 512 for VALID)
-    h = jnp.tanh(AnalogLinear.apply(params["W3"], h, ks[2], lr=lr,
-                                    mode=cfg.layer_mode("W3"),
-                                    cfg=cfg.cfg("W3")))
-    logits = AnalogLinear.apply(params["W4"], h, ks[3], lr=lr,
-                                mode=cfg.layer_mode("W4"),
-                                cfg=cfg.cfg("W4"))   # (B, 10)
+    with jax.named_scope("W3"):
+        h = AnalogLinear.apply(params["W3"], h, ks[2], lr=lr,
+                               mode=cfg.layer_mode("W3"), cfg=cfg.cfg("W3"))
+    h = jnp.tanh(h)
+    with jax.named_scope("W4"):
+        logits = AnalogLinear.apply(params["W4"], h, ks[3], lr=lr,
+                                    mode=cfg.layer_mode("W4"),
+                                    cfg=cfg.cfg("W4"))   # (B, 10)
     return logits
 
 
