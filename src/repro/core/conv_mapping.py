@@ -30,8 +30,13 @@ pulse-stream tensors — only one chunk of columns/streams is live at a time:
   the per-device bound clip land once at the end, exactly where the
   materialized cycle applies them (``update.pulse_update_streamed``).
 
-``conv_stream_chunk=None`` runs a single chunk — the materialized path —
-and is the bit-parity oracle for every chunked configuration with a
+``conv_stream_chunk=None`` runs a single chunk — the materialized path.
+Its columns are the ``kh*kw`` static strided tap slices of the padded
+volume, and its col2im adds each tap back as one padded volume
+(``lax.pad``, the slice's transpose): pure data movement with no index
+gather or scatter, and bit-identical to them (``gather_columns``,
+``col2im_add``).  Smaller chunks gather and scatter-add by index.  The
+single chunk is the bit-parity oracle for every chunked configuration with a
 fixed-latency BM mode (off / two-phase; tests/test_conv_stream.py).  The
 one exception is *iterative* BM with read noise: its halve-and-retry
 while_loop decides re-reads from the whole call batch, so chunked loops
@@ -99,42 +104,12 @@ def im2col(x: Array, kernel: IntPair, stride: IntPair = 1,
     dilated-patches conv (which contracts against a ``C*kh*kw``-channel
     identity kernel — O(C^2 k^4) multiply work, and its transpose dominates
     the backward cycle on CPU).  Slicing is pure data movement, and its
-    autodiff transpose is a cheap scatter-add col2im.
+    autodiff transpose is a cheap pad-and-add col2im.
     """
-    kh, kw = _pair(kernel)
-    sh, sw = _pair(stride)
-    dh, dw = _pair(dilation)
-    b, h, w, c = x.shape
-    ekh, ekw = (kh - 1) * dh + 1, (kw - 1) * dw + 1  # effective kernel extent
-    if not isinstance(padding, str):
-        # explicit per-dim pad pairs ((top, bottom), (left, right)),
-        # as accepted by lax conv padding
-        (pt, pb), (pl, pr) = padding
-        x = jnp.pad(x, ((0, 0), (pt, pb), (pl, pr), (0, 0)))
-        b, h, w, c = x.shape
-        oh, ow = (h - ekh) // sh + 1, (w - ekw) // sw + 1
-    elif padding.upper() == "SAME":
-        oh, ow = -(-h // sh), -(-w // sw)
-        ph = max(0, (oh - 1) * sh + ekh - h)
-        pw = max(0, (ow - 1) * sw + ekw - w)
-        x = jnp.pad(x, ((0, 0), (ph // 2, ph - ph // 2),
-                        (pw // 2, pw - pw // 2), (0, 0)))
-        b, h, w, c = x.shape
-    elif padding.upper() == "VALID":
-        oh, ow = (h - ekh) // sh + 1, (w - ekw) // sw + 1
-    else:
-        raise ValueError(f"unsupported padding {padding!r}")
-    cols = []
-    for ih in range(kh):
-        for iw in range(kw):
-            r0, c0 = ih * dh, iw * dw
-            cols.append(jax.lax.slice(
-                x, (0, r0, c0, 0),
-                (b, r0 + (oh - 1) * sh + 1, c0 + (ow - 1) * sw + 1, c),
-                (1, sh, sw, 1)))
-    patches = jnp.stack(cols, axis=-2)           # (B, H', W', kh*kw, C)
-    patches = jnp.swapaxes(patches, -1, -2)      # (B, H', W', C, kh*kw)
-    return patches.reshape(b, oh, ow, c * kh * kw)
+    geom = conv_geometry(x.shape, kernel, stride, padding, dilation,
+                         bias=False)
+    cols = _tap_columns(_pad_volume(x, geom), geom)
+    return cols.reshape(geom.b, geom.oh, geom.ow, geom.features)
 
 
 def kernel_matrix_from_conv(kernels: Array) -> Array:
@@ -264,17 +239,39 @@ def gather_columns(xpad: Array, geom: ConvGeom, start, chunk: int) -> Array:
     """Materialize one chunk of im2col columns ``(chunk, cols)`` from the
     padded activation volume (channel-major feature order, bias ones
     appended) — the only patch storage the streaming path ever creates.
-    Rows past the last position are zero (they drive nothing)."""
-    b_idx, i, j, valid = _position_indices(geom, start, chunk)
-    rowi = (i[:, None, None] * geom.sh
-            + (np.arange(geom.kh) * geom.dh)[None, :, None])   # (chunk, kh, 1)
-    coli = (j[:, None, None] * geom.sw
-            + (np.arange(geom.kw) * geom.dw)[None, None, :])   # (chunk, 1, kw)
-    g = xpad[b_idx[:, None, None], rowi, coli, :]          # (chunk, kh, kw, C)
-    g = jnp.moveaxis(g, -1, 1).reshape(chunk, geom.features)
+
+    A chunk that covers every position (``chunk == geom.positions``, the
+    materialized path) is built from the ``kh*kw`` static strided tap
+    slices, as :func:`im2col` builds it; ``start`` is then 0 and ignored.
+    A smaller chunk gathers its rows by index (rows past the last position
+    are zero: they drive nothing).  Both are pure data movement, so the two
+    paths give the same matrix bit for bit; the slices avoid an
+    element-by-element XLA gather."""
+    if chunk == geom.positions:
+        g, valid = _tap_columns(xpad, geom), None
+    else:
+        b_idx, i, j, valid = _position_indices(geom, start, chunk)
+        rowi = (i[:, None, None] * geom.sh                 # (chunk, kh, 1)
+                + (np.arange(geom.kh) * geom.dh)[None, :, None])
+        coli = (j[:, None, None] * geom.sw                 # (chunk, 1, kw)
+                + (np.arange(geom.kw) * geom.dw)[None, None, :])
+        g = xpad[b_idx[:, None, None], rowi, coli, :]      # (chunk, kh, kw, C)
+        g = jnp.moveaxis(g, -1, 1).reshape(chunk, geom.features)
     if geom.bias:
         g = jnp.concatenate([g, jnp.ones((chunk, 1), g.dtype)], axis=1)
-    return jnp.where(valid[:, None], g, 0)
+    return g if valid is None else jnp.where(valid[:, None], g, 0)
+
+
+def _tap_columns(xpad: Array, geom: ConvGeom) -> Array:
+    """Every position's patch row ``(positions, features)`` from the
+    ``kh*kw`` strided tap slices: tap ``(ih, iw)`` of channel ``c`` lands
+    in feature ``c*kh*kw + ih*kw + iw``.  A single channel is dropped
+    before stacking, so no size-1 axis sits beside the tap axis."""
+    p = geom.positions
+    taps = [geom.tap_slice(xpad, ih, iw) for ih, iw in geom.taps]
+    if geom.c == 1:
+        return jnp.stack([t.reshape(p) for t in taps], axis=-1)
+    return jnp.stack(taps, axis=-1).reshape(p, geom.features)
 
 
 def window_absmax(xpad: Array, geom: ConvGeom) -> Array:
@@ -288,11 +285,20 @@ def window_absmax(xpad: Array, geom: ConvGeom) -> Array:
     return m
 
 
+def _rounded(z: Array) -> Array:
+    """``z`` itself (a NaN stays a NaN), behind a select that compilers do
+    not see through.  XLA fuses the op that produced ``z`` into the
+    col2im adds; where that op is a multiply, a backend may contract the
+    pair into one fused multiply-add, which rounds once where the index
+    scatter-add (whose updates are stored first) rounds twice."""
+    return jnp.where(jnp.isnan(z), jnp.nan, z)
+
+
 @jax.named_scope("col2im")
 def col2im_add(z: Array, geom: ConvGeom, start, chunk: int,
                xbar: Array) -> Array:
-    """Scatter-add one chunk's transpose-read columns ``(chunk, features)``
-    into the padded volume cotangent.
+    """Add one chunk's transpose-read columns ``(chunk, features)`` into
+    the padded volume cotangent.
 
     Taps are applied in DESCENDING order: a pixel's contributing positions
     are strictly decreasing in tap order, so ascending-chunk x
@@ -300,7 +306,28 @@ def col2im_add(z: Array, geom: ConvGeom, start, chunk: int,
     global descending-tap order *regardless of the chunk size* — chunked
     and materialized backward cycles are bit-identical (f32 addition is
     not associative; a chunk-dependent order would drift ulps).
+
+    A chunk that covers every position adds each tap as one interior- and
+    edge-padded volume (``lax.pad``, the transpose of
+    :meth:`ConvGeom.tap_slice`) instead of a scatter-add.  It is exact:
+    within one tap no two positions share a pixel, and ``xbar`` starts at
+    +0.0 and so never holds -0.0, which makes adding 0 to the pixels the
+    tap misses an identity.  A smaller chunk scatter-adds by index.
     """
+    if chunk == geom.positions:
+        z4 = _rounded(z).reshape(geom.positions, geom.c, geom.kh, geom.kw)
+        zt = jnp.moveaxis(z4, 1, -1)                     # (P, kh, kw, C)
+        zero = jnp.zeros((), z.dtype)
+        for ih, iw in reversed(geom.taps):
+            r0, c0 = ih * geom.dh, iw * geom.dw
+            hi_r = geom.h - r0 - (geom.oh - 1) * geom.sh - 1
+            hi_c = geom.w - c0 - (geom.ow - 1) * geom.sw - 1
+            z_tap = zt[:, ih, iw].reshape(geom.b, geom.oh, geom.ow, geom.c)
+            xbar = xbar + jax.lax.pad(
+                z_tap, zero,
+                ((0, 0, 0), (r0, hi_r, geom.sh - 1),
+                 (c0, hi_c, geom.sw - 1), (0, 0, 0)))
+        return xbar
     b_idx, i, j, valid = _position_indices(geom, start, chunk)
     z3 = jnp.where(valid[:, None], z, 0).reshape(
         chunk, geom.c, geom.kh, geom.kw)

@@ -265,3 +265,93 @@ def test_chunked_cycles_cross_product(nm, bm, dpw, grid, pallas):
                     use_pallas=pallas)
     _assert_cycles_match(cfg, _chunked(cfg, 11), state_kw=dict(cout=4),
                          x=_x((2, 8, 8, 3), seed=2))
+
+
+# ---------------------------------------------------------------------------
+# One-chunk conv mapping: tap slices and pad-and-add, bit-equal to the
+# index gather and scatter-add that smaller chunks use
+# ---------------------------------------------------------------------------
+
+_GEOMS = {
+    "lenet_k1": ((8, 28, 28, 1), 5, 1, "VALID", 1, True),
+    "lenet_k2": ((8, 12, 12, 16), 5, 1, "VALID", 1, True),
+    "stride_dilation_explicit_pad": ((2, 9, 8, 3), 3, (2, 1),
+                                     ((2, 1), (0, 3)), (1, 2), True),
+    "same": ((2, 9, 8, 3), (3, 2), 1, "SAME", 1, True),
+    "no_bias": ((2, 10, 10, 3), 3, 1, "VALID", 1, False),
+}
+
+
+def _geom_and_volume(name):
+    shape, kernel, stride, pad, dil, bias = _GEOMS[name]
+    geom = cm.conv_geometry(shape, kernel, stride, pad, dil, bias=bias)
+    xpad = cm._pad_volume(_x(shape, seed=3), geom)
+    return geom, xpad
+
+
+def _chunked_col2im(z, geom, chunk):
+    """The chunk loop ``_stream_backward`` runs, over index scatter-adds."""
+    nchunks = -(-geom.positions // chunk)
+    zp = jnp.pad(z, ((0, nchunks * chunk - geom.positions), (0, 0)))
+
+    def body(ci, xbar):
+        start = ci * chunk
+        zc = jax.lax.dynamic_slice_in_dim(zp, start, chunk)
+        return cm.col2im_add(zc, geom, start, chunk, xbar)
+
+    xbar = jnp.zeros((geom.b, geom.h, geom.w, geom.c), z.dtype)
+    return jax.lax.fori_loop(0, nchunks, body, xbar)
+
+
+@pytest.mark.parametrize("name", list(_GEOMS))
+def test_one_chunk_columns_bit_match_gathered_chunks(name):
+    geom, xpad = _geom_and_volume(name)
+    whole = cm.gather_columns(xpad, geom, 0, geom.positions)
+    parts = [cm.gather_columns(xpad, geom, s, 7)
+             for s in range(0, geom.positions, 7)]
+    gathered = jnp.concatenate(parts)[:geom.positions]
+    assert whole.shape == (geom.positions, geom.cols)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(gathered))
+
+
+@pytest.mark.parametrize("name", list(_GEOMS))
+def test_one_chunk_col2im_bit_matches_chunked_scatter(name):
+    """``z`` is made by a multiply inside the compiled program, as the
+    transpose read's scaling makes it, so a multiply-add contraction across
+    the col2im adds would show."""
+    geom, _ = _geom_and_volume(name)
+    a = jax.random.normal(jax.random.key(4), (geom.positions, geom.features))
+    s = jax.random.uniform(jax.random.key(5), (geom.positions, 1),
+                           minval=0.5, maxval=2.0)
+    zero = jnp.zeros((geom.b, geom.h, geom.w, geom.c), a.dtype)
+    whole = jax.jit(lambda aa, ss: cm.col2im_add(aa * ss, geom, 0,
+                                                 geom.positions, zero))(a, s)
+    chunked = jax.jit(lambda aa, ss: _chunked_col2im(aa * ss, geom, 7))(a, s)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(chunked))
+
+
+def _primitives(jaxpr):
+    """Every primitive name in a jaxpr, sub-jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("name", ["lenet_k1", "lenet_k2"])
+def test_one_chunk_conv_mapping_has_no_gather_or_scatter(name):
+    """The materialized path moves data by static slices and pads; only a
+    chunk smaller than the positions gathers and scatter-adds by index."""
+    geom, xpad = _geom_and_volume(name)
+    xbar = jnp.zeros_like(xpad)
+    for chunk, indexed in [(geom.positions, False), (7, True)]:
+        z = jnp.zeros((chunk, geom.features))
+        cols = _primitives(jax.make_jaxpr(
+            lambda x: cm.gather_columns(x, geom, 0, chunk))(xpad).jaxpr)
+        back = _primitives(jax.make_jaxpr(
+            lambda zz, xb: cm.col2im_add(zz, geom, 0, chunk, xb))(
+                z, xbar).jaxpr)
+        assert ("gather" in cols) is indexed, (chunk, sorted(cols))
+        assert ("scatter-add" in back) is indexed, (chunk, sorted(back))
