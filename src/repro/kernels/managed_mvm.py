@@ -113,12 +113,21 @@ def vmem_limit(nbytes: int, name: str) -> int:
 
 
 def managed_read_vmem(bm: int, bk: int, outp: int, out_f_p: int) -> int:
-    """Working-set bytes of one :func:`managed_mvm_pallas` launch."""
+    """Working-set bytes of one :func:`managed_mvm_pallas` launch.
+
+    Compiled for v5e at 2048, 4096 and 11008 output columns, a launch holds
+    10 (rows, out) f32 blocks with one row block and 12 with more (256
+    rows and up): with one row block the output is written once and needs
+    no buffer pair of its own.  The count here is 12 (the weight and output
+    pairs, three accumulators, and an epilogue of one temporary more than
+    :data:`EPILOGUE_TEMPS`), so it holds at every row count; at 11 it fell
+    3.5 MiB short of the 65.1 MiB an 11008-column read from 256 rows
+    needs."""
     return (2 * (tile_bytes(bm, bk) + tile_bytes(outp, bk)      # x, w
                  + tile_bytes(bm, 1)                            # nm scale
                  + tile_bytes(bm, out_f_p) + tile_bytes(bm, 1))  # y, sat
             + 3 * tile_bytes(bm, outp) + 2 * tile_bytes(bm, 1)  # scratch
-            + EPILOGUE_TEMPS * tile_bytes(bm, outp))
+            + (EPILOGUE_TEMPS + 1) * tile_bytes(bm, outp))      # epilogue
 
 
 # ---------------------------------------------------------------------------

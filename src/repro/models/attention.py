@@ -68,7 +68,8 @@ def _project_qkv(p, x_q: Array, x_kv: Array, cfg: ModelConfig, akey=None):
 
     def dense(name, xx, i):
         k = None if akey is None else jax.random.fold_in(akey, i)
-        y = L.dense_apply(p[name], xx, key=k)
+        with jax.named_scope(name):
+            y = L.dense_apply(p[name], xx, key=k)
         if cfg.qkv_bias and name + "b" in p:
             y = y + p[name + "b"].astype(y.dtype)
         return y
@@ -118,6 +119,10 @@ def _flash(q: Array, k: Array, v: Array, *, causal: bool, window: int,
     def per_q_chunk(qi, q_blk):
         q_pos = q_pos_base + qi * cq                     # (cq,)
 
+        # recomputed in the backward pass: only the running (m, l, acc)
+        # carry is kept per block, not the (cq, ck) scores and weights, so
+        # training keeps attention memory O(S * chunk) as well
+        @jax.checkpoint
         def kv_step(carry, inp):
             m, l, acc = carry
             ki, k_blk, v_blk = inp
@@ -180,7 +185,8 @@ def forward(p, x: Array, cfg: ModelConfig, *, positions: Array,
                      chunk_q=chunk_q, chunk_k=chunk_k)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     okey = None if akey is None else jax.random.fold_in(akey, 3)
-    y = L.dense_apply(p["o"], out, key=okey)
+    with jax.named_scope("o"):
+        y = L.dense_apply(p["o"], out, key=okey)
     y = shard(y, "batch", "seq", "embed_act")
     if return_kv:
         return y, (k, v)
@@ -229,7 +235,8 @@ def decode(p, x_t: Array, cache_k: Array, cache_v: Array, pos: Array,
     out = jnp.einsum("bhqk,bkhd->bqhd", a, vv)
     out = out.reshape(*x_t.shape[:-1], cfg.n_heads * cfg.head_dim)
     okey = None if akey is None else jax.random.fold_in(akey, 3)
-    y = L.dense_apply(p["o"], out, key=okey)
+    with jax.named_scope("o"):
+        y = L.dense_apply(p["o"], out, key=okey)
     return y, cache_k, cache_v
 
 

@@ -38,7 +38,8 @@ def apply(p, x: Array, cfg: ModelConfig, akey=None) -> Array:
 
     def dense(name, xx, i):
         k = None if ks is None else ks[i]
-        return L.dense_apply(p[name], xx, key=k)
+        with jax.named_scope(name):
+            return L.dense_apply(p[name], xx, key=k)
 
     h = jax.nn.silu(dense("wg", x, 0)) * dense("wi", x, 1)
     h = shard(h, "batch", "seq", "mlp")

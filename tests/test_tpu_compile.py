@@ -84,7 +84,7 @@ _MANAGED = dataclasses.replace(dev.rpu_nm_bm_um_bl1(), bm_mode="two_phase",
     (128, 513, 1, True),        # LeNet W3 transpose read
     (13 * 32, 401, 13, False),  # LeNet K2 (13 devices) on the gather path
     (11008, 4096, 1, False),    # deepseek_7b MLP up/gate projection
-    (18048, 4096, 1, False),    # the widest output the VMEM gate admits
+    (16640, 4096, 1, False),    # the widest output the VMEM gate admits
 ])
 def test_managed_read_compiles(one_chip, compiled_kernels, m, n, d_avg,
                                transpose):
@@ -98,8 +98,32 @@ def test_managed_read_compiles(one_chip, compiled_kernels, m, n, d_avg,
     assert _n_kernels(c) == 1
 
 
+@pytest.mark.parametrize("m, n, rows, transpose", [
+    (11008, 4096, 256, False),   # deepseek_7b wi/wg forward, two row blocks
+    (11008, 4096, 8192, False),  # ... from a 4 x 2048-token train step
+    (4096, 4096, 256, False),    # q, k, v, o forward
+    (4096, 4096, 8192, False),
+    (11008, 4096, 8192, True),   # wi/wg transpose read, 3 array segments
+    (4096, 11008, 8192, False),  # wo forward read, 3 array segments
+    (16640, 4096, 256, False),   # the widest output the VMEM gate admits
+])
+def test_managed_read_compiles_from_many_rows(one_chip, compiled_kernels, m,
+                                              n, rows, transpose):
+    """With more than one 128-row block the output block's buffer pair is
+    live beside the epilogue (``managed_read_vmem``): the LM train step's
+    reads compile, each to one kernel."""
+    from repro.kernels import ops
+    c = _compile(lambda w, x, k: ops.managed_mvm(w, x, k, _MANAGED,
+                                                 transpose=transpose,
+                                                 backward=transpose),
+                 _sds((m, n), one_chip),
+                 _sds((rows, m if transpose else n), one_chip),
+                 _key(one_chip))
+    assert _n_kernels(c) == 1
+
+
 @pytest.mark.parametrize("kind, admitted, refused", [
-    ("managed_read", 18048, 18176),          # output columns
+    ("managed_read", 16640, 16768),          # output columns
     ("bwd_update", 1792, 1920),              # square tile side
     ("managed_read_conv", 17152, 17280),     # K2-geometry output channels
 ])
