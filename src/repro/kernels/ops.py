@@ -335,7 +335,8 @@ def pulse_counts(streams_rows: Array, streams_cols: Array
     the chunked-update accumulation entry (``core.update.stream_counts``).
 
     Bit-identical to ``update.coincidence_counts`` (the counts are integer
-    sums of {0, 1} products in f32) and to the count stage of the fused
+    sums of {0, 1} products in f32, contracted in one bf16 MXU pass whatever
+    the ambient matmul precision) and to the count stage of the fused
     ``pulse_update_pallas`` launch, so chunked pallas updates accumulate
     counts that finalize to exactly the materialized fused result.
     """

@@ -234,6 +234,19 @@ def test_pulse_counts_compiles(one_chip, compiled_kernels):
     assert _n_kernels(c) == 1
 
 
+@pytest.mark.parametrize("m, n", [(11008, 4096), (4096, 11008)])
+def test_pulse_counts_compiles_at_lm_widths(one_chip, compiled_kernels, m, n):
+    """The LM cell's counts launch (4 x 2048 stream slots, deepseek_7b's
+    MLP tiles) under the cell's highest ambient precision: 512-blocks of
+    bf16 streams, one kernel."""
+    from repro.kernels import ops
+    t = 8192
+    with jax.default_matmul_precision("highest"):
+        c = _compile(ops.pulse_counts, _sds((t, m), one_chip),
+                     _sds((t, n), one_chip))
+    assert _n_kernels(c) == 1
+
+
 def test_fused_lenet_step_compiles_to_eight_kernels(one_chip,
                                                     compiled_kernels):
     """The audited fused LeNet train step compiles for the chip with
