@@ -33,7 +33,7 @@ LENET_BATCH = 8
 
 #: serving audit policy: the managed LM preset with the fixed-latency BM
 #: mode, so the whole managed read fuses into ONE Pallas launch per
-#: converted site (iterative BM cannot fuse — kernels/ops.managed_mvm
+#: converted site (iterative BM cannot fuse — management.launch_args
 #: rejects it)
 SERVE_POLICY = "lm_managed:use_pallas=true:bm_mode=two_phase"
 

@@ -39,6 +39,7 @@ from repro.core import tile as tile_lib
 from repro.core import update as update_lib
 from repro.core.device import RPUConfig, sample_device_maps
 from repro.core.tile import TileState
+from repro.kernels.bwd_update_mvm import bwd_update_eligible
 
 Array = jax.Array
 
@@ -70,14 +71,6 @@ def _pulse_w_bar(cfg, w, maps, x, g, key, lr):
     return (w - new_w).astype(w.dtype)
 
 
-def _fuse_eligible(cfg: RPUConfig, w: Array) -> bool:
-    """Static routing decision for the fused backward+update launch."""
-    if not cfg.fuse_bwd_update:
-        return False
-    from repro.kernels.bwd_update_mvm import bwd_update_eligible
-    return bwd_update_eligible(cfg, w.shape)
-
-
 @jax.named_scope("backward_update")
 def _fused_bwd(cfg, w, maps, x, g, k_b, k_u, lr):
     """Backward + update cycles in one Pallas launch — bit-identical to
@@ -105,7 +98,7 @@ def _analog_mat_bwd(cfg, res, g):
     w, dw_up, dw_dn, bound, x, key, lr = res
     _, k_b, k_u = _split3(key)
     maps = tile_lib.DeviceMaps(dw_up=dw_up, dw_dn=dw_dn, bound=bound)
-    if _fuse_eligible(cfg, w):
+    if bwd_update_eligible(cfg, w.shape):
         x_bar, w_bar = _fused_bwd(cfg, w, maps, x, g, k_b, k_u, lr)
     else:
         x_bar = _bwd_read(cfg, w, g, k_b)
@@ -136,7 +129,7 @@ def _analog_seeded_bwd(cfg, res, g):
     w, seed, x, key, lr = res
     _, k_b, k_u = _split3(key)
     maps = sample_device_maps(seed, w.shape[0], w.shape[1], cfg)
-    if _fuse_eligible(cfg, w):
+    if bwd_update_eligible(cfg, w.shape):
         x_bar, w_bar = _fused_bwd(cfg, w, maps, x, g, k_b, k_u, lr)
     else:
         x_bar = _bwd_read(cfg, w, g, k_b)

@@ -59,11 +59,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import analog_linear
+from repro.core import analog_linear, management
 from repro.core import tile as tile_lib
 from repro.core import update as update_lib
 from repro.core.device import RPUConfig, sample_device_maps
 from repro.core.tile import TileState
+from repro.kernels import ops as kops
+from repro.kernels.bwd_update_mvm import conv_bwd_update_eligible
+from repro.kernels.conv_mvm import conv_kernel_eligible
 
 Array = jax.Array
 IntPair = Union[int, Tuple[int, int]]
@@ -354,7 +357,6 @@ def _conv_nm_scale(xpad: Array, geom: ConvGeom) -> Array:
     of the (never materialized) column rows, from the running window max.
     Order-exact: ``max`` commutes, so this equals the materialized scale
     bit-for-bit (the bias contributes a constant 1 to every row max)."""
-    from repro.core import management
     s = window_absmax(xpad, geom).reshape(geom.positions, 1)
     if geom.bias:
         return jnp.maximum(s, jnp.asarray(1.0, s.dtype))
@@ -365,18 +367,16 @@ def _conv_nm_scale(xpad: Array, geom: ConvGeom) -> Array:
 def _stream_forward(cfg: RPUConfig, geom: ConvGeom, w: Array, x: Array,
                     k_f: Array) -> Array:
     """Forward cycle: managed reads over position-column chunks."""
-    from repro.kernels import conv_mvm  # local: kernels import core
     xpad = _pad_volume(x, geom)
     total = geom.positions
     chunk, nchunks = _chunking(cfg, geom)
     state = TileState(w=w, maps=None, seed=k_f)  # maps unused in reads
 
-    if conv_mvm.conv_kernel_eligible(cfg, geom, w.shape):
-        from repro.kernels import ops as kops
-        use_nm = cfg.noise_management and cfg.nm_forward
-        nm_s = (_conv_nm_scale(xpad, geom) if use_nm
+    if conv_kernel_eligible(cfg, geom, w.shape):
+        nm_s = (_conv_nm_scale(xpad, geom) if cfg.nm_active(backward=False)
                 else jnp.ones((total, 1), x.dtype))
-        y2, _ = kops.conv_managed_mvm(w, xpad, geom, nm_s, k_f, cfg)
+        args = management.launch_args(cfg, k_f, backward=False, nm_s=nm_s)
+        y2, _ = kops.conv_managed_mvm(w, xpad, geom, args, cfg)
         return y2.reshape(geom.b, geom.oh, geom.ow, -1)
 
     out_f = w.shape[0] // cfg.devices_per_weight
@@ -461,14 +461,6 @@ def _div_replicas(z: Array, d: int) -> Array:
     return z / d
 
 
-def _conv_fuse_eligible(cfg: RPUConfig, geom: ConvGeom, w: Array) -> bool:
-    """Static routing decision for the fused conv backward+update launch."""
-    if not cfg.fuse_bwd_update:
-        return False
-    from repro.kernels.bwd_update_mvm import conv_bwd_update_eligible
-    return conv_bwd_update_eligible(cfg, geom, w.shape)
-
-
 @jax.named_scope("backward_update")
 def _fused_bwd_update(cfg: RPUConfig, geom: ConvGeom, w, maps, x, g, k_b,
                       k_u, lr) -> Tuple[Array, Array]:
@@ -476,9 +468,6 @@ def _fused_bwd_update(cfg: RPUConfig, geom: ConvGeom, w, maps, x, g, k_b,
     (``kernels.bwd_update_mvm.conv_bwd_update_pallas``) — bit-identical to
     ``_stream_backward`` + ``_stream_pulse_w_bar`` (the separate-launch
     oracle, kept for ineligible shapes and as the parity reference)."""
-    from repro.core import update as update_lib
-    from repro.kernels import ops as kops
-
     xpad = _pad_volume(x, geom)
     total = geom.positions
     d = cfg.devices_per_weight
@@ -494,9 +483,11 @@ def _fused_bwd_update(cfg: RPUConfig, geom: ConvGeom, w, maps, x, g, k_b,
         um_maxima = (x_max, jnp.max(jnp.abs(-g2)))
 
     k_a, k_b2, k_c = jax.random.split(k_u, 3)
+    args = management.launch_args(cfg, k_b, delta_rep, backward=True,
+                                  stream_keys=(k_a, k_b2), lr=lr,
+                                  um_maxima=um_maxima)
     z, _sat, count_up, count_dn = kops.conv_bwd_update_mvm(
-        w, xpad, delta_rep, geom, k_b, k_a, k_b2, cfg, lr,
-        um_maxima=um_maxima)
+        w, xpad, delta_rep, geom, args, cfg)
     if d > 1:
         # jit so #_d is a trace-time constant: the oracle's division runs
         # inside the streaming fori_loop trace, where XLA simplifies the
@@ -534,7 +525,7 @@ def _conv_stream_seeded_bwd(cfg, geom, res, g):
     w, seed, x, key, lr = res
     _, k_b, k_u = analog_linear._split3(key)
     maps = sample_device_maps(seed, w.shape[0], w.shape[1], cfg)
-    if _conv_fuse_eligible(cfg, geom, w):
+    if conv_bwd_update_eligible(cfg, geom, w.shape):
         x_bar, w_bar = _fused_bwd_update(cfg, geom, w, maps, x, g, k_b,
                                          k_u, lr)
     else:
@@ -566,7 +557,7 @@ def _conv_stream_mat_bwd(cfg, geom, res, g):
     w, dw_up, dw_dn, bound, x, key, lr = res
     _, k_b, k_u = analog_linear._split3(key)
     maps = tile_lib.DeviceMaps(dw_up=dw_up, dw_dn=dw_dn, bound=bound)
-    if _conv_fuse_eligible(cfg, geom, w):
+    if conv_bwd_update_eligible(cfg, geom, w.shape):
         x_bar, w_bar = _fused_bwd_update(cfg, geom, w, maps, x, g, k_b,
                                          k_u, lr)
     else:

@@ -63,7 +63,6 @@ class RPUConfig:
                                        # latency, effective bound 16*alpha,
                                        # no data-dependent control flow)
     update_management: bool = False    # UM — rebalance Cx / Cdelta by sqrt(dmax/xmax)
-    update_bl_management: bool = False # reserved: dynamic BL (beyond-paper)
     # --- multi-device mapping (variability reduction) ------------------------
     devices_per_weight: int = 1        # #_d physical devices per logical weight
     # --- physical array-size limit (Discussion: max 4096x4096) --------------
@@ -105,6 +104,37 @@ class RPUConfig:
     use_pallas: bool = False           # route MVM/update through Pallas kernels
     fast_rng: bool = True              # counter-hash RNG for bulk pulse streams
                                        # (mirrors the TPU kernel's on-chip PRNG)
+
+    # Derived rules: each defined here and read everywhere else ------------
+    @property
+    def bm_active(self) -> bool:
+        """BM (Eq. 4) acts: requested, and the integrator bound is finite."""
+        return self.bound_management and self.out_bound != float("inf")
+
+    @property
+    def bm_iterative(self) -> bool:
+        """BM runs the paper's data-dependent halve-and-retry loop — one
+        raw read per retry, which no fused launch can take."""
+        return self.bm_active and self.bm_mode != "two_phase"
+
+    @property
+    def grid_routed(self) -> bool:
+        """The cycles route through the sub-tile grid
+        (``core/tile_grid.py``).  The trivial (1, 1) grid stays on the
+        single-tile path, which is bit-identical and keeps the fused
+        launches."""
+        return self.tile_grid is not None and tuple(self.tile_grid) != (1, 1)
+
+    def nm_active(self, backward: bool) -> bool:
+        """NM (Eq. 3) scales this cycle's inputs: the backward cycle's
+        whenever NM is on, the forward cycle's only with ``nm_forward``."""
+        return self.noise_management and (backward or self.nm_forward)
+
+    def read_sigma(self, transpose: bool) -> float:
+        """Additive read-noise sigma of a forward (``transpose=False``) or
+        transpose read."""
+        on = self.noise_backward if transpose else self.noise_forward
+        return self.read_noise if on else 0.0
 
     # Ideal-device toggles used by the Fig. 3 / Fig. 4 ablations ------------
     def without_variations(self) -> "RPUConfig":

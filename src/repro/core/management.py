@@ -6,7 +6,9 @@ All three are *digital-domain rescalings* wrapped around the analog array
 operations — they never change the analog circuit model, exactly as the paper
 prescribes.  They are written as pure functions over an ``analog_mvm``
 callable so the same code wraps the pure-jnp reference tile, the Pallas
-kernels, and sharded multi-pod tiles.
+kernels, and sharded multi-pod tiles.  A fused launch, which applies NM and
+BM inside one kernel, takes its management operands from
+:func:`launch_args` instead, with the same key discipline.
 
 Scale threading
 ---------------
@@ -42,12 +44,13 @@ with zero extra logic here.
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.device import RPUConfig
+from repro.utils import fastrng
 
 Array = jax.Array
 AnalogMVM = Callable[[Array, Array], Tuple[Array, Array]]
@@ -187,20 +190,96 @@ def with_management(analog_mvm: AnalogMVM, x: Array, key: Array,
     two-phase 1/16 read also saturated).  Without BM the flag is the raw
     per-vector saturation of the single read.
     """
-    use_nm = cfg.noise_management and (backward or cfg.nm_forward)
+    use_nm = cfg.nm_active(backward)
     s_nm = nm_scale(x) if use_nm else None
 
-    if cfg.bound_management and cfg.out_bound != float("inf"):
-        if cfg.bm_mode == "two_phase":
-            return with_bound_management_two_phase(
-                analog_mvm, x, key, init_scale=s_nm)
+    if cfg.bm_iterative:
         return with_bound_management(
             analog_mvm, x, key, cfg.bm_max_iters, init_scale=s_nm)
+    if cfg.bm_active:
+        return with_bound_management_two_phase(
+            analog_mvm, x, key, init_scale=s_nm)
 
     if use_nm:
         y, sat = analog_mvm(x / s_nm, key)
         return y * s_nm, sat
     return analog_mvm(x, key)
+
+
+# ---------------------------------------------------------------------------
+# Management operands of a fused launch
+# ---------------------------------------------------------------------------
+
+class LaunchArgs(NamedTuple):
+    """What a fused managed-read launch takes from the management layer.
+
+    ``nm_s``: (B, 1) per-vector digital scale (ones when NM is off);
+    ``read_seeds``: (2,) u32 seeds of the two BM reads; ``two_phase`` and
+    ``retry_scale`` are static.  ``upd_seeds`` and ``gains`` (``(C_x,
+    C_d)``, f32) are set for the fused backward+update launches only.
+    """
+    nm_s: jax.Array
+    read_seeds: jax.Array
+    two_phase: bool
+    retry_scale: float
+    upd_seeds: Optional[jax.Array] = None
+    gains: Optional[jax.Array] = None
+
+
+def launch_args(cfg: RPUConfig, key: Array, rows: Optional[Array] = None,
+                *, backward: bool, nm_s: Optional[Array] = None,
+                stream_keys: Optional[Tuple[Array, Array]] = None, lr=None,
+                cols: Optional[Array] = None, um_maxima=None,
+                row_offset=None) -> LaunchArgs:
+    """Build a fused launch's management operands from the key and config.
+
+    The key discipline is :func:`with_management`'s, so a fused launch
+    draws the noise of the reference pipeline bit for bit: the two-phase
+    reads consume ``jax.random.split(key)``, a single read consumes ``key``
+    itself (its seed is passed twice).  ``rows`` (B, n) are the read's input
+    vectors, from which the NM scale is taken when NM applies to this cycle;
+    a caller that holds no such matrix (the conv read) passes ``nm_s``.
+
+    A fused backward+update launch also passes ``stream_keys``: the A-stream
+    (columns) and B-stream (rows) keys of the update key's 3-way split
+    (``k_c`` stays with the caller for ``update.finalize_counts``).  Its
+    pulse gains come from the column drivers ``cols`` against the negated
+    ``rows`` (dense), or from precomputed ``um_maxima`` (conv: the columns
+    are never materialized).  ``row_offset``, when given, rides as the third
+    stream seed word: the dense launch's streaming counter shift.
+
+    Iterative BM is data-dependent and multi-launch by nature: it is
+    refused here, for every fused and managed entry point.
+    """
+    if cfg.bm_iterative:
+        raise ValueError(
+            "iterative BM cannot be fused into one launch; use "
+            "management.with_bound_management over noisy_mvm")
+    if nm_s is None:
+        nm_s = (nm_scale(rows) if cfg.nm_active(backward)
+                else jnp.ones((rows.shape[0], 1), rows.dtype))
+    two_phase = cfg.bm_active
+    if two_phase:
+        k1, k2 = jax.random.split(key)
+        read_seeds = jnp.stack([fastrng.key_to_seed(k1),
+                                fastrng.key_to_seed(k2)])
+    else:
+        s1 = fastrng.key_to_seed(key)
+        read_seeds = jnp.stack([s1, s1])
+    upd_seeds = gains = None
+    if stream_keys is not None:
+        off = ([] if row_offset is None
+               else [jnp.asarray(row_offset, jnp.uint32)])
+        upd_seeds = jnp.stack([fastrng.key_to_seed(k) for k in stream_keys]
+                              + off)
+        if cols is not None:
+            cx, cd = um_factors(cols, -rows, cfg, lr)
+        else:
+            cx, cd = um_factors_from_maxima(um_maxima, cfg, lr)
+        gains = jnp.stack([jnp.asarray(cx, jnp.float32),
+                           jnp.asarray(cd, jnp.float32)])
+    return LaunchArgs(nm_s, read_seeds, two_phase, TWO_PHASE_SCALE,
+                      upd_seeds, gains)
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +309,19 @@ def um_factors_from_max(x_max: Array, d_max: Array, cfg: RPUConfig,
     # Guard against degenerate extremes early in training (all-zero errors).
     m = jnp.clip(m, 1e-3, 1e3)
     return (c * m).astype(dtype), (c / m).astype(dtype)
+
+
+def um_factors_from_maxima(um_maxima: Optional[Tuple[Array, Array]],
+                           cfg: RPUConfig, lr: float) -> Tuple[Array, Array]:
+    """:func:`um_factors_from_max` over an optional ``(x_max, d_max)``
+    pair, in ``cfg.dtype`` — the streamed update cycles' gains.  The pair
+    may be ``None`` only when UM is off."""
+    if um_maxima is None:
+        assert not cfg.update_management, (
+            "update management over streamed chunks needs precomputed "
+            "(x_max, d_max) extrema")
+        um_maxima = (None, None)
+    return um_factors_from_max(*um_maxima, cfg, lr, cfg.dtype)
 
 
 def um_factors(x: Array, d: Array, cfg: RPUConfig, lr: float,

@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core.device import DeviceMaps, RPUConfig, sample_device_maps
 from repro.core import management
+from repro.kernels import ops as kops
 
 Array = jax.Array
 
@@ -146,7 +147,6 @@ def analog_mvm(w: Array, x: Array, key: jax.Array, cfg: RPUConfig,
     independent read noise and are bounded *before* the digital summation.
     """
     if cfg.use_pallas:
-        from repro.kernels import ops as kops
         return kops.noisy_mvm(w, x, key, cfg, transpose=transpose,
                               row_offset=row_offset, total_rows=total_rows)
     return analog_mvm_reference(w, x, key, cfg, transpose=transpose,
@@ -174,8 +174,7 @@ def analog_mvm_reference(w: Array, x: Array, key: jax.Array, cfg: RPUConfig,
 
     batch_shape = x.shape[:-1]
     alpha = jnp.asarray(cfg.out_bound, x.dtype)
-    noise = cfg.read_noise if (cfg.noise_backward if transpose
-                               else cfg.noise_forward) else 0.0
+    noise = cfg.read_sigma(transpose)
 
     def _normal(k, shape, per_row):
         if cfg.fast_rng:
@@ -221,12 +220,6 @@ def analog_mvm_reference(w: Array, x: Array, key: jax.Array, cfg: RPUConfig,
 # Managed tile cycles (forward / backward)
 # ---------------------------------------------------------------------------
 
-def _bm_is_iterative(cfg: RPUConfig) -> bool:
-    """True when BM runs the data-dependent halve-and-retry while_loop."""
-    return (cfg.bound_management and cfg.out_bound != float("inf")
-            and cfg.bm_mode != "two_phase")
-
-
 def managed_mvm_reference(w: Array, x: Array, key: jax.Array, cfg: RPUConfig,
                           *, transpose: bool = False, backward: bool = False,
                           row_offset=None, total_rows: Optional[int] = None
@@ -246,6 +239,27 @@ def managed_mvm_reference(w: Array, x: Array, key: jax.Array, cfg: RPUConfig,
                                     total_rows=total_rows)
 
     return management.with_management(mvm, x, key, cfg, backward=backward)
+
+
+def managed_mvm(w: Array, x: Array, key: jax.Array, cfg: RPUConfig, *,
+                transpose: bool = False, backward: bool = False,
+                row_offset=None, total_rows: Optional[int] = None
+                ) -> Tuple[Array, Array]:
+    """Kernel-backed managed read: NM scale, fixed-latency BM (off /
+    two-phase), clipping and — on the forward read — the #_d replica
+    average in ONE Pallas launch (``kops.managed_mvm``).
+
+    The management operands are built here (``management.launch_args``)
+    with :func:`managed_mvm_reference`'s key discipline, so the launch draws
+    the reference's noise bit for bit.  ``x``: (..., n) with any leading
+    batch shape.  Iterative BM is refused (``ValueError``): its retry loop
+    wraps one raw read per retry instead.
+    """
+    x2d = x.reshape(-1, x.shape[-1])
+    args = management.launch_args(cfg, key, x2d, backward=backward)
+    y2d, sat = kops.managed_mvm(w, x2d, args, cfg, transpose=transpose,
+                                row_offset=row_offset, total_rows=total_rows)
+    return y2d.reshape(*x.shape[:-1], -1), sat.reshape(x.shape[:-1])
 
 
 def _replica_mean(y_phys: Array, d: int) -> Array:
@@ -274,14 +288,6 @@ def replicate_delta(delta: Array, d: int,
     return delta
 
 
-def _grid_routed(cfg: RPUConfig) -> bool:
-    """True when tile cycles route through the sub-tile grid subsystem
-    (``core/tile_grid.py``).  The trivial (1, 1) grid stays on the plain
-    single-tile path, which is bit-identical and keeps the fused
-    ``managed_mvm`` Pallas launch."""
-    return cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1)
-
-
 def tile_forward(state: TileState, x: Array, key: jax.Array,
                  cfg: RPUConfig, *, return_sat: bool = False,
                  row_offset=None, total_rows: Optional[int] = None):
@@ -300,18 +306,17 @@ def tile_forward(state: TileState, x: Array, key: jax.Array,
     """
     d = cfg.devices_per_weight
 
-    if _grid_routed(cfg):
+    if cfg.grid_routed:
         from repro.core import tile_grid  # local import, avoids cycle
         return tile_grid.grid_tile_forward(state, x, key, cfg,
                                            return_sat=return_sat,
                                            row_offset=row_offset,
                                            total_rows=total_rows)
 
-    if cfg.use_pallas and not _bm_is_iterative(cfg):
-        from repro.kernels import ops as kops
-        y, sat = kops.managed_mvm(state.w, x, key, cfg, transpose=False,
-                                  backward=False, row_offset=row_offset,
-                                  total_rows=total_rows)
+    if cfg.use_pallas and not cfg.bm_iterative:
+        y, sat = managed_mvm(state.w, x, key, cfg, transpose=False,
+                             backward=False, row_offset=row_offset,
+                             total_rows=total_rows)
         return (y, sat) if return_sat else y
 
     def mvm(xx, kk):
@@ -336,18 +341,17 @@ def tile_backward(state: TileState, delta: Array, key: jax.Array,
     d = cfg.devices_per_weight
     delta = replicate_delta(delta, d, rows_phys=state.w.shape[0])
 
-    if _grid_routed(cfg):
+    if cfg.grid_routed:
         from repro.core import tile_grid  # local import, avoids cycle
         return tile_grid.grid_tile_backward(state, delta, key, cfg,
                                             return_sat=return_sat,
                                             row_offset=row_offset,
                                             total_rows=total_rows)
 
-    if cfg.use_pallas and not _bm_is_iterative(cfg):
-        from repro.kernels import ops as kops
-        z, sat = kops.managed_mvm(state.w, delta, key, cfg, transpose=True,
-                                  backward=True, row_offset=row_offset,
-                                  total_rows=total_rows)
+    if cfg.use_pallas and not cfg.bm_iterative:
+        z, sat = managed_mvm(state.w, delta, key, cfg, transpose=True,
+                             backward=True, row_offset=row_offset,
+                             total_rows=total_rows)
     else:
         def mvm(dd, kk):
             return analog_mvm(state.w, dd, kk, cfg, transpose=True,
@@ -383,17 +387,41 @@ def tile_backward_update(w: Array, maps: DeviceMaps, x: Array, g: Array,
     ``W_eff^T g`` and the post-update physical weights.
     """
     from repro.core import update as update_lib  # local import, avoids cycle
-    from repro.kernels import ops as kops
 
-    d = cfg.devices_per_weight
-    g_rep = replicate_delta(g, d, rows_phys=w.shape[0])
     k_a, k_b, k_c = jax.random.split(k_upd, 3)
-    z, _sat, count_up, count_dn = kops.bwd_update_mvm(
-        w, x, g_rep, k_read, k_a, k_b, cfg, lr)
-    if d > 1:
-        z = z / d
+    z, count_up, count_dn = backward_update_counts(w, x, g, k_read, k_a, k_b,
+                                                   cfg, lr)
     new_w = update_lib.finalize_counts(w, maps, count_up, count_dn, k_c, cfg)
     return z, new_w
+
+
+def backward_update_counts(w: Array, x: Array, g: Array, k_read: jax.Array,
+                           k_a: jax.Array, k_b: jax.Array, cfg: RPUConfig,
+                           lr, row_offset=0) -> Tuple[Array, Array, Array]:
+    """The fused launch of :func:`tile_backward_update`, before the
+    finalize: the replica-averaged managed transpose read of ``g`` and the
+    integer coincidence counts of the update driven by ``x`` and ``-g``.
+
+    ``k_a``/``k_b``: the A/B stream keys of the update key's 3-way split.
+    ``row_offset`` (may be traced) shifts the stream counters by that many
+    logical update rows — the ``update.sample_signed_streams`` streaming
+    discipline, so a launch over rows ``[r0, r0 + B)`` of a larger update
+    batch (one timestep of a recurrent sequence) draws the exact row slice
+    of the single-shot streams and its counts accumulate to the unchunked
+    cycle bit for bit.  Returns ``(z, count_up, count_dn)``.
+    """
+    d = cfg.devices_per_weight
+    g_rep = replicate_delta(g, d, rows_phys=w.shape[0])
+    d2d = g_rep.reshape(-1, w.shape[0])
+    x2d = x.reshape(-1, x.shape[-1])
+    args = management.launch_args(cfg, k_read, d2d, backward=True,
+                                  stream_keys=(k_a, k_b), lr=lr, cols=x2d,
+                                  row_offset=row_offset)
+    z, _sat, count_up, count_dn = kops.bwd_update_mvm(w, x2d, d2d, args, cfg)
+    z = z.reshape(*g_rep.shape[:-1], -1)
+    if d > 1:
+        z = z / d
+    return z, count_up, count_dn
 
 
 def tile_update(state: TileState, x: Array, delta: Array, key: jax.Array,

@@ -156,10 +156,10 @@ def grid_is_sharded(cfg: RPUConfig) -> bool:
     """True when ``cfg`` routes tile cycles through a *sharded* grid (i.e.
     a crossbar mesh will claim healthy devices).  Used by the training
     engines to reject conflicting data-parallel meshes."""
-    if cfg.tile_grid is None:
+    if not cfg.grid_routed:
         return False
     gr, gc = cfg.tile_grid
-    return gr * gc > 1 and _n_healthy() >= gr * gc
+    return _n_healthy() >= gr * gc
 
 
 def _block_key(key: Array, flat_index, n_blocks: int) -> Array:
@@ -491,10 +491,9 @@ def grid_pulse_update_streamed(w: Array, maps: DeviceMaps, src, get_chunk,
     must be zeroed.  Mirrors ``grid_pulse_update``'s chunked branch with
     the gather inside each (per-device) chunk round — bit-identical to the
     materialized grid cycle, zero collectives in the update."""
-    from repro.core import update as update_lib2  # _um_from_maxima
     g = TileGrid.for_tile(w.shape, cfg)
     k_a, k_b, k_c = jax.random.split(key, 3)
-    cx, cd = update_lib2._um_from_maxima(um_maxima, cfg, lr)
+    cx, cd = management.um_factors_from_maxima(um_maxima, cfg, lr)
     wp, mp = g.pad_w(w), _pad_maps(maps, g)
 
     def get_padded(s, start, n):

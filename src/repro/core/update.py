@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.device import DeviceMaps, RPUConfig
-from repro.core.management import um_factors
+from repro.core.management import um_factors, um_factors_from_maxima
+from repro.kernels import ops as kops
 
 Array = jax.Array
 
@@ -150,7 +151,6 @@ def stream_counts(x: Array, delta: Array, cx: Array, cd: Array,
     b = sample_signed_streams(k_b, delta, cd, cfg.bl, cfg.fast_rng,
                               row_offset=row_offset)
     if cfg.use_pallas:
-        from repro.kernels import ops as kops
         return kops.pulse_counts(b, a)
     return coincidence_counts(b, a)
 
@@ -218,11 +218,11 @@ def pulse_update(w: Array, maps: DeviceMaps, x: Array, delta: Array,
     applies them — so chunked updates are bit-identical to the unchunked
     cycle while never materializing the full pulse-stream tensors.
     """
-    from repro.core.tile import _grid_routed, replicate_delta  # avoids cycle
+    from repro.core.tile import replicate_delta  # avoids cycle
     delta = replicate_delta(delta, cfg.devices_per_weight,
                             rows_phys=w.shape[0])
 
-    if _grid_routed(cfg):
+    if cfg.grid_routed:
         from repro.core import tile_grid
         return tile_grid.grid_pulse_update(w, maps, x, delta, key, cfg, lr)
 
@@ -245,11 +245,7 @@ def pulse_update(w: Array, maps: DeviceMaps, x: Array, delta: Array,
         # function the reference and chunked paths use, which pins all
         # pulse-update paths (reference / pallas x chunked / unchunked)
         # bit-identical to each other — the counts are exact integers, so
-        # only the shared finalize touches inexact arithmetic.  (The fully
-        # fused single-launch variant, ``ops.pulse_update_fused``, keeps
-        # maps/ctoc/clip on-chip but compiles its finalize arithmetic
-        # separately — ulp-level differences — and remains available for
-        # TPU runs that prefer fusion over cross-path bit-parity.)
+        # only the shared finalize touches inexact arithmetic.
         k_a, k_b, k_c = jax.random.split(key, 3)
         cx, cd = um_factors(x, delta, cfg, lr)
         count_up, count_dn = stream_counts(x, delta, cx, cd, k_a, k_b, cfg)
@@ -276,14 +272,14 @@ def pulse_update_streamed(w: Array, maps: DeviceMaps, src, get_chunk,
     chunked counts accumulate exactly, maps/ctoc/clip land once at the end,
     and each chunk's streams use counter-offset draws.
     """
-    if _grid_routed_cfg(cfg):
+    if cfg.grid_routed:
         from repro.core import tile_grid
         return tile_grid.grid_pulse_update_streamed(
             w, maps, src, get_chunk, key, cfg, lr, total=total, chunk=chunk,
             um_maxima=um_maxima)
 
     k_a, k_b, k_c = jax.random.split(key, 3)
-    cx, cd = _um_from_maxima(um_maxima, cfg, lr)
+    cx, cd = um_factors_from_maxima(um_maxima, cfg, lr)
     nchunks = -(-total // chunk)
 
     def body(c, carry):
@@ -297,22 +293,6 @@ def pulse_update_streamed(w: Array, maps: DeviceMaps, src, get_chunk,
     zeros = jnp.zeros(w.shape, jnp.float32)
     count_up, count_dn = jax.lax.fori_loop(0, nchunks, body, (zeros, zeros))
     return finalize_counts(w, maps, count_up, count_dn, k_c, cfg)
-
-
-def _grid_routed_cfg(cfg: RPUConfig) -> bool:
-    from repro.core.tile import _grid_routed  # avoids cycle
-    return _grid_routed(cfg)
-
-
-def _um_from_maxima(um_maxima, cfg: RPUConfig, lr: float):
-    from repro.core.management import um_factors_from_max
-    if um_maxima is None:
-        assert not cfg.update_management, (
-            "update management over streamed chunks needs precomputed "
-            "(x_max, d_max) extrema")
-        return um_factors_from_max(None, None, cfg, lr, cfg.dtype)
-    x_max, d_max = um_maxima
-    return um_factors_from_max(x_max, d_max, cfg, lr, cfg.dtype)
 
 
 def expected_update(x: Array, delta: Array, cfg: RPUConfig, lr: float

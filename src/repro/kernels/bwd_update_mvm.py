@@ -80,19 +80,22 @@ def _dense_vmem(bm: int, bk: int, kp: int, n_p: int) -> int:
             + 2 * tile_bytes(bk, n_p))                    # per-step counts
 
 
+def _config_fuses(cfg) -> bool:
+    """The config part of both gates: fusion requested, pallas on,
+    counter-offset RNG, no sharded tile grid (grid cycles shard per
+    sub-tile) and no iterative BM (multi-launch by nature)."""
+    return (cfg.fuse_bwd_update and cfg.use_pallas and cfg.fast_rng
+            and not cfg.grid_routed and not cfg.bm_iterative)
+
+
 def bwd_update_eligible(cfg, w_shape: Tuple[int, int],
                         bm: int = 128, bk: int = 128) -> bool:
     """True when the fused backward+update kernel can take a dense layer's
     backward pass: fusion requested, pallas on, fixed-latency BM, single
     transpose-read segment, no sharded tile grid, counter-offset RNG, and
     the launch's working set within the VMEM cap its wrapper claims."""
-    if not (cfg.fuse_bwd_update and cfg.use_pallas and cfg.fast_rng):
+    if not _config_fuses(cfg):
         return False
-    if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
-        return False                      # grid cycles shard per sub-tile
-    if (cfg.bound_management and cfg.out_bound != float("inf")
-            and cfg.bm_mode != "two_phase"):
-        return False                      # iterative BM is multi-launch
     m_phys, n_cols = w_shape
     if m_phys > cfg.max_array_rows:
         return False                      # transpose read would segment
@@ -330,12 +333,7 @@ def conv_bwd_update_eligible(cfg, geom, w_shape: Tuple[int, int],
     conv layer's backward pass — the conv analogue of
     :func:`bwd_update_eligible` (per-image working set, patch tile and
     both count blocks within the VMEM cap its wrapper claims)."""
-    if not (cfg.fuse_bwd_update and cfg.use_pallas and cfg.fast_rng):
-        return False
-    if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
-        return False
-    if (cfg.bound_management and cfg.out_bound != float("inf")
-            and cfg.bm_mode != "two_phase"):
+    if not _config_fuses(cfg):
         return False
     m_phys, n_cols = w_shape
     if m_phys > cfg.max_array_rows:

@@ -71,13 +71,8 @@ def conv_kernel_eligible(cfg, geom, w_shape: Tuple[int, int]) -> bool:
     pallas on, fixed-latency BM (off / two-phase), a single physical
     contraction segment, and the per-image working set within the VMEM
     cap the wrapper claims."""
-    if not cfg.use_pallas:
-        return False
-    if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
-        return False                      # grid reads shard per sub-tile
-    if (cfg.bound_management and cfg.out_bound != float("inf")
-            and cfg.bm_mode != "two_phase"):
-        return False                      # iterative BM is multi-launch
+    if not cfg.use_pallas or cfg.grid_routed or cfg.bm_iterative:
+        return False                      # no pallas / per-shard / retry loop
     if geom.cols > cfg.max_array_cols:
         return False                      # would need contraction segments
     d_avg = cfg.devices_per_weight

@@ -1,27 +1,31 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""Launch wrappers around the Pallas kernels.
 
-These adapt the (config-carrying, arbitrary-batch-shape) tile API onto the
-2-D padded kernel interfaces and pick interpret mode automatically off the
-TPU (the kernels then execute in Python for correctness validation; the TPU
-is the target).  Every shape launches its kernel: there is no fallback to
-the pure-jnp reference, and a shape whose blocks cannot fit the TPU's VMEM
-raises (``managed_mvm.vmem_limit``).
+Each wrapper takes what ``repro.core`` decided — operand arrays, the
+management operands of ``core.management.launch_args``, and the config
+whose physical parameters fix the static scalars — names the launch, picks
+interpret mode off the TPU (the kernels then execute in Python for
+correctness validation; the TPU is the target) and launches.  No
+simulation rule is decided here.  Every shape launches its kernel: there is
+no fallback to the pure-jnp reference, and a shape whose blocks cannot fit
+the TPU's VMEM raises (``managed_mvm.vmem_limit``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import functools
 from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.device import DeviceMaps, RPUConfig
+from repro.core.device import RPUConfig
+from repro.kernels.bwd_update_mvm import (bwd_update_mvm_pallas,
+                                          conv_bwd_update_pallas)
+from repro.kernels.conv_mvm import conv_managed_mvm_pallas
 from repro.kernels.managed_mvm import managed_mvm_pallas
 from repro.kernels.noisy_mvm import noisy_mvm_pallas
-from repro.kernels.pulse_update import pulse_counts_pallas, pulse_update_pallas
+from repro.kernels.pulse_update import pulse_counts_pallas
 from repro.utils import fastrng
 
 Array = jax.Array
@@ -30,12 +34,12 @@ Array = jax.Array
 # Stable launch labeling (repro.analysis.jaxpr_audit attribution hook)
 # ---------------------------------------------------------------------------
 # Every Pallas launch this module issues carries a stable *kind* name
-# (``managed_read``, ``noisy_read``, ``pulse_update``, ``pulse_counts``,
-# ``managed_read_conv``) as the kernel name, so static-analysis passes over
-# traced jaxprs can count launches per kind without pattern-matching
-# internals.  ``launch_label`` optionally appends a trace-time label
-# (``managed_read__K2``; ``__`` because pallas mangles brackets in kernel
-# names): the auditor wraps per-layer traces in it to
+# (``managed_read``, ``noisy_read``, ``pulse_counts``, ``managed_read_conv``,
+# ``bwd_update``, ``bwd_update_conv``) as the kernel name, so static-analysis
+# passes over traced jaxprs can count launches per kind without
+# pattern-matching internals.  ``launch_label`` optionally appends a
+# trace-time label (``managed_read__K2``; ``__`` because pallas mangles
+# brackets in kernel names): the auditor wraps per-layer traces in it to
 # attribute launch counts to layers.  The label only changes the kernel
 # *name* — numerics and lowering are identical with or without it.
 
@@ -68,6 +72,15 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _segments(w: Array, cfg: RPUConfig, transpose: bool) -> int:
+    """Physical arrays the read's contraction dim spans (each is a separate
+    integration with its own noise and bound)."""
+    r, c = w.shape
+    if transpose:
+        return max(1, -(-r // cfg.max_array_rows))
+    return max(1, -(-c // cfg.max_array_cols))
+
+
 def noisy_mvm(w: Array, x: Array, key: Array, cfg: RPUConfig, *,
               transpose: bool = False, row_offset=None,
               total_rows: int = None) -> Tuple[Array, Array]:
@@ -83,18 +96,13 @@ def noisy_mvm(w: Array, x: Array, key: Array, cfg: RPUConfig, *,
     (docs/scaling.md), so the sharded path keeps NM/BM in the digital
     domain around per-phase ``noisy_mvm`` launches."""
     r, c = w.shape
-    contraction = r if transpose else c
-    limit = cfg.max_array_rows if transpose else cfg.max_array_cols
-    n_seg = max(1, -(-contraction // limit))
-
     batch_shape = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
-    sigma = cfg.read_noise if (cfg.noise_backward if transpose
-                               else cfg.noise_forward) else 0.0
     seed = fastrng.key_to_seed(key)
     y2d, satblk = noisy_mvm_pallas(
-        w, x2d, seed, sigma=float(sigma), alpha=float(cfg.out_bound),
-        n_seg=n_seg, transpose=transpose, row_offset=row_offset,
+        w, x2d, seed, sigma=float(cfg.read_sigma(transpose)),
+        alpha=float(cfg.out_bound), n_seg=_segments(w, cfg, transpose),
+        transpose=transpose, row_offset=row_offset,
         total_rows=total_rows, interpret=_interpret_default(),
         name=launch_name("noisy_read"))
     sat = jnp.any(satblk > 0, axis=-1)
@@ -103,172 +111,79 @@ def noisy_mvm(w: Array, x: Array, key: Array, cfg: RPUConfig, *,
             sat.reshape(batch_shape))
 
 
-def managed_mvm(w: Array, x: Array, key: Array, cfg: RPUConfig, *,
-                transpose: bool = False, backward: bool = False,
-                row_offset=None, total_rows: int = None
-                ) -> Tuple[Array, Array]:
-    """Kernel-backed *managed* analog read: NM scale, fixed-latency BM
-    (off / two-phase), clipping and the #_d replica average in ONE Pallas
-    launch (``managed_mvm_pallas``).
+def managed_mvm(w: Array, x2d: Array, args, cfg: RPUConfig, *,
+                transpose: bool = False, row_offset=None,
+                total_rows: int = None) -> Tuple[Array, Array]:
+    """ONE fused managed-read launch (``managed_mvm_pallas``): NM scale,
+    fixed-latency BM (off / two-phase), clipping and the #_d replica average
+    of the forward read.
 
-    Key discipline mirrors ``core.tile.managed_mvm_reference`` exactly: the
-    two-phase reads consume ``jax.random.split(key)``, a single read consumes
-    ``key`` itself — so the fused kernel draws bit-identical noise to the
-    reference pipeline.  Iterative BM is data-dependent multi-launch by
-    nature and must go through ``management.with_bound_management`` over
-    ``noisy_mvm`` instead.
+    ``x2d``: (B, n) input vectors; ``args``: their
+    ``core.management.launch_args`` (NM scale, read seeds, BM phases).
+    Returns ``(y, residual_sat)``: ``(B, out)`` and ``(B,)``.  The kernel
+    cannot take iterative BM, whose retry loop wraps one ``noisy_mvm``
+    launch per read instead.
     """
-    from repro.core import management
-
-    r, c = w.shape
-    contraction = r if transpose else c
-    limit = cfg.max_array_rows if transpose else cfg.max_array_cols
-    n_seg = max(1, -(-contraction // limit))
-    d_avg = 1 if transpose else cfg.devices_per_weight
-
-    use_bm = cfg.bound_management and cfg.out_bound != float("inf")
-    if use_bm and cfg.bm_mode != "two_phase":
-        raise ValueError(
-            "iterative BM cannot be fused into one launch; use "
-            "management.with_bound_management over noisy_mvm")
-    use_nm = cfg.noise_management and (backward or cfg.nm_forward)
-
-    batch_shape = x.shape[:-1]
-    x2d = x.reshape(-1, x.shape[-1])
-    nm_s = (management.nm_scale(x2d) if use_nm
-            else jnp.ones((x2d.shape[0], 1), x2d.dtype))
-    sigma = cfg.read_noise if (cfg.noise_backward if transpose
-                               else cfg.noise_forward) else 0.0
-    if use_bm:
-        k1, k2 = jax.random.split(key)
-        seeds = jnp.stack([fastrng.key_to_seed(k1), fastrng.key_to_seed(k2)])
-    else:
-        s1 = fastrng.key_to_seed(key)
-        seeds = jnp.stack([s1, s1])
-
-    y2d, sat = managed_mvm_pallas(
-        w, x2d, nm_s, seeds, sigma=float(sigma), alpha=float(cfg.out_bound),
-        n_seg=n_seg, transpose=transpose, two_phase=use_bm,
-        retry_scale=float(management.TWO_PHASE_SCALE), d_avg=d_avg,
+    return managed_mvm_pallas(
+        w, x2d, args.nm_s, args.read_seeds,
+        sigma=float(cfg.read_sigma(transpose)), alpha=float(cfg.out_bound),
+        n_seg=_segments(w, cfg, transpose), transpose=transpose,
+        two_phase=args.two_phase, retry_scale=float(args.retry_scale),
+        d_avg=1 if transpose else cfg.devices_per_weight,
         row_offset=row_offset, total_rows=total_rows,
         interpret=_interpret_default(),
         name=launch_name("managed_read"))
-    out_f = c if transpose else r // d_avg
-    return (y2d.reshape(*batch_shape, out_f), sat.reshape(batch_shape))
 
 
-def conv_managed_mvm(w: Array, xpad: Array, geom, nm_s: Array, key: Array,
-                     cfg: RPUConfig) -> Tuple[Array, Array]:
+def conv_managed_mvm(w: Array, xpad: Array, geom, args, cfg: RPUConfig
+                     ) -> Tuple[Array, Array]:
     """Kernel-backed implicit-im2col managed conv read
     (``conv_mvm_pallas``): the patch tiles are assembled in VMEM from the
     activation volume — no im2col gather in HBM at any chunk size.
 
-    ``nm_s``: (positions, 1) per-position digital scale (the window max the
-    caller computes without materializing columns; ones when NM is off).
-    Key/seed discipline matches :func:`managed_mvm` exactly, so the conv
-    kernel draws bit-identical noise to the gather + fused-read path.
+    ``args``: ``core.management.launch_args`` with ``nm_s`` the
+    (positions, 1) per-position scale (the window max the caller computes
+    without materializing columns; ones when NM is off) and the read seeds
+    of :func:`managed_mvm`, so the conv kernel draws bit-identical noise to
+    the gather + fused-read path.
     """
-    from repro.core import management
-    from repro.kernels.conv_mvm import conv_managed_mvm_pallas
-
-    use_bm = cfg.bound_management and cfg.out_bound != float("inf")
-    if use_bm and cfg.bm_mode != "two_phase":
-        raise ValueError(
-            "iterative BM cannot be fused into one launch; use "
-            "management.with_bound_management over noisy_mvm")
-    sigma = cfg.read_noise if cfg.noise_forward else 0.0
-    if use_bm:
-        k1, k2 = jax.random.split(key)
-        seeds = jnp.stack([fastrng.key_to_seed(k1), fastrng.key_to_seed(k2)])
-    else:
-        s1 = fastrng.key_to_seed(key)
-        seeds = jnp.stack([s1, s1])
     return conv_managed_mvm_pallas(
-        w, xpad, nm_s, seeds, geom=geom, sigma=float(sigma),
-        alpha=float(cfg.out_bound), two_phase=use_bm,
-        retry_scale=float(management.TWO_PHASE_SCALE),
+        w, xpad, args.nm_s, args.read_seeds, geom=geom,
+        sigma=float(cfg.read_sigma(False)), alpha=float(cfg.out_bound),
+        two_phase=args.two_phase, retry_scale=float(args.retry_scale),
         d_avg=cfg.devices_per_weight, interpret=_interpret_default(),
         name=launch_name("managed_read_conv"))
 
 
-def bwd_update_mvm(w: Array, x: Array, g_rep: Array, read_key: Array,
-                   k_a: Array, k_b: Array, cfg: RPUConfig, lr: float,
-                   row_offset=None) -> Tuple[Array, Array, Array, Array]:
+def bwd_update_mvm(w: Array, x2d: Array, d2d: Array, args,
+                   cfg: RPUConfig) -> Tuple[Array, Array, Array, Array]:
     """ONE fused launch for the backward + update cycles of a dense tile
-    (``bwd_update_mvm_pallas``): the managed transpose read of ``g_rep``
+    (``bwd_update_mvm_pallas``): the managed transpose read of ``d2d``
     AND the signed pulse streams + integer coincidence counts, without the
     streams or the transpose-read intermediates ever reaching HBM.
 
-    Disciplines mirror the separate launches exactly so the fused result is
-    *bit-identical*: the read consumes ``read_key`` per :func:`managed_mvm`
-    (split when two-phase, same seed twice otherwise; NM is always active on
-    the backward cycle when ``cfg.noise_management``); the update's A/B
-    streams consume ``k_a``/``k_b`` from the caller's 3-way split of the
-    update key (``k_c`` stays with the caller for
-    ``update.finalize_counts``), with gains from the same ``um_factors``
-    call ``core.update.pulse_update`` makes.
-
-    ``g_rep``: (..., m_phys) *replicated* upstream gradient (positive —
-    the kernel negates it for the update's row drivers, matching the
-    reference's ``pulse_update(..., -g, ...)``).  ``x``: (..., n) update
-    column drivers.  Returns ``(z, residual_sat, count_up, count_dn)`` —
-    ``z`` on physical columns (caller divides by #_d), counts ready for
-    the shared digital finalize.
-
-    ``row_offset`` (may be traced) shifts the A/B stream counters by that
-    many logical update rows — the ``update.sample_signed_streams``
-    streaming-chunk discipline, so a launch over rows ``[r0, r0 + B)`` of a
-    larger update batch (one timestep chunk of a recurrent sequence) draws
-    the exact row slice of the single-shot streams and its counts
-    accumulate to the unchunked cycle bit-for-bit.
+    ``d2d``: (B, m_phys) *replicated* upstream gradient (positive — the
+    kernel negates it for the update's row drivers, matching the
+    reference's ``pulse_update(..., -g, ...)``); ``x2d``: (B, n) update
+    column drivers.  ``args``: ``core.management.launch_args`` with the
+    read half (as :func:`managed_mvm`'s, NM on the backward cycle) and the
+    update half (A/B stream seeds plus the streaming row offset, pulse
+    gains).  Returns ``(z, residual_sat, count_up, count_dn)`` — ``z`` on
+    physical columns (the caller divides by #_d), counts ready for the
+    shared digital finalize.
     """
-    from repro.core import management
-    from repro.kernels.bwd_update_mvm import bwd_update_mvm_pallas
-
     assert cfg.fast_rng, "fused backward+update generates streams on-chip " \
                          "from the counter-hash PRNG (requires cfg.fast_rng)"
-    m_phys, n_cols = w.shape
-    use_bm = cfg.bound_management and cfg.out_bound != float("inf")
-    if use_bm and cfg.bm_mode != "two_phase":
-        raise ValueError(
-            "iterative BM cannot be fused into one launch; use "
-            "management.with_bound_management over noisy_mvm")
-
-    batch_shape = g_rep.shape[:-1]
-    d2d = g_rep.reshape(-1, m_phys)
-    x2d = x.reshape(-1, x.shape[-1])
-    # backward cycle: NM applies whenever enabled (management.with_management
-    # with backward=True), independent of nm_forward
-    nm_s = (management.nm_scale(d2d) if cfg.noise_management
-            else jnp.ones((d2d.shape[0], 1), d2d.dtype))
-    sigma = cfg.read_noise if cfg.noise_backward else 0.0
-    if use_bm:
-        k1, k2 = jax.random.split(read_key)
-        read_seeds = jnp.stack([fastrng.key_to_seed(k1),
-                                fastrng.key_to_seed(k2)])
-    else:
-        s1 = fastrng.key_to_seed(read_key)
-        read_seeds = jnp.stack([s1, s1])
-    off = (jnp.zeros((), jnp.uint32) if row_offset is None
-           else jnp.asarray(row_offset, jnp.uint32))
-    upd_seeds = jnp.stack([fastrng.key_to_seed(k_a),
-                           fastrng.key_to_seed(k_b), off])
-    cx, cd = management.um_factors(x2d, -d2d, cfg, lr)
-    gains = jnp.stack([cx, cd])
-
-    z2d, sat, up, dn = bwd_update_mvm_pallas(
-        w, d2d, x2d, nm_s, read_seeds, upd_seeds, gains,
-        sigma=float(sigma), alpha=float(cfg.out_bound), two_phase=use_bm,
-        retry_scale=float(management.TWO_PHASE_SCALE), bl=int(cfg.bl),
-        interpret=_interpret_default(), name=launch_name("bwd_update"))
-    return (z2d.reshape(*batch_shape, n_cols), sat.reshape(batch_shape),
-            up, dn)
+    return bwd_update_mvm_pallas(
+        w, d2d, x2d, args.nm_s, args.read_seeds, args.upd_seeds, args.gains,
+        sigma=float(cfg.read_sigma(True)), alpha=float(cfg.out_bound),
+        two_phase=args.two_phase, retry_scale=float(args.retry_scale),
+        bl=int(cfg.bl), interpret=_interpret_default(),
+        name=launch_name("bwd_update"))
 
 
-def conv_bwd_update_mvm(w: Array, xpad: Array, delta_rep: Array, geom,
-                        read_key: Array, k_a: Array, k_b: Array,
-                        cfg: RPUConfig, lr: float, um_maxima=None
-                        ) -> Tuple[Array, Array, Array, Array]:
+def conv_bwd_update_mvm(w: Array, xpad: Array, delta_rep: Array, geom, args,
+                        cfg: RPUConfig) -> Tuple[Array, Array, Array, Array]:
     """Fused backward+update launch for a streaming conv tile
     (``conv_bwd_update_pallas``): the managed transpose read of the
     replicated position-error rows AND the pulse streams over the
@@ -276,57 +191,20 @@ def conv_bwd_update_mvm(w: Array, xpad: Array, delta_rep: Array, geom,
 
     ``xpad``: padded activation volume (B, Hp, Wp, C) — the update's column
     drivers are assembled in VMEM from it (never an HBM im2col).
-    ``delta_rep``: (positions, m_phys) replicated error rows.  ``um_maxima``
-    follows ``update.pulse_update_streamed`` (precomputed scalar extrema —
-    required under update management).  Key/seed discipline matches
-    :func:`bwd_update_mvm`.  Returns ``(z, residual_sat, count_up,
-    count_dn)`` with ``z`` (positions, cols) on physical columns.
+    ``delta_rep``: (positions, m_phys) replicated error rows.  ``args`` as
+    :func:`bwd_update_mvm`'s, without the row offset.  Returns ``(z,
+    residual_sat, count_up, count_dn)`` with ``z`` (positions, cols) on
+    physical columns.
     """
-    from repro.core import management, update as update_lib
-    from repro.kernels.bwd_update_mvm import conv_bwd_update_pallas
-
     assert cfg.fast_rng, "fused backward+update generates streams on-chip " \
                          "from the counter-hash PRNG (requires cfg.fast_rng)"
-    use_bm = cfg.bound_management and cfg.out_bound != float("inf")
-    if use_bm and cfg.bm_mode != "two_phase":
-        raise ValueError(
-            "iterative BM cannot be fused into one launch; use "
-            "management.with_bound_management over noisy_mvm")
-    nm_s = (management.nm_scale(delta_rep) if cfg.noise_management
-            else jnp.ones((delta_rep.shape[0], 1), delta_rep.dtype))
-    sigma = cfg.read_noise if cfg.noise_backward else 0.0
-    if use_bm:
-        k1, k2 = jax.random.split(read_key)
-        read_seeds = jnp.stack([fastrng.key_to_seed(k1),
-                                fastrng.key_to_seed(k2)])
-    else:
-        s1 = fastrng.key_to_seed(read_key)
-        read_seeds = jnp.stack([s1, s1])
-    upd_seeds = jnp.stack([fastrng.key_to_seed(k_a), fastrng.key_to_seed(k_b)])
-    cx, cd = update_lib._um_from_maxima(um_maxima, cfg, lr)
-    gains = jnp.stack([jnp.asarray(cx, jnp.float32),
-                       jnp.asarray(cd, jnp.float32)])
-
     return conv_bwd_update_pallas(
-        w, xpad, delta_rep, nm_s, read_seeds, upd_seeds, gains, geom=geom,
-        sigma=float(sigma), alpha=float(cfg.out_bound), two_phase=use_bm,
-        retry_scale=float(management.TWO_PHASE_SCALE), bl=int(cfg.bl),
+        w, xpad, delta_rep, args.nm_s, args.read_seeds, args.upd_seeds,
+        args.gains, geom=geom, sigma=float(cfg.read_sigma(True)),
+        alpha=float(cfg.out_bound), two_phase=args.two_phase,
+        retry_scale=float(args.retry_scale), bl=int(cfg.bl),
         interpret=_interpret_default(),
         name=launch_name("bwd_update_conv"))
-
-
-def pulse_update_fused(w: Array, maps: DeviceMaps, streams_rows: Array,
-                       streams_cols: Array, key: Array,
-                       cfg: RPUConfig) -> Array:
-    """Kernel-backed update cycle; streams already sampled (..., BL, n)."""
-    m, n = w.shape
-    rows2 = streams_rows.reshape(-1, m)
-    cols2 = streams_cols.reshape(-1, n)
-    seed = fastrng.key_to_seed(key)
-    return pulse_update_pallas(
-        w, maps.dw_up, maps.dw_dn, maps.bound, rows2, cols2, seed,
-        ctoc=float(cfg.dw_min_ctoc), interpret=_interpret_default(),
-        name=launch_name("pulse_update"))
 
 
 def pulse_counts(streams_rows: Array, streams_cols: Array
@@ -336,9 +214,8 @@ def pulse_counts(streams_rows: Array, streams_cols: Array
 
     Bit-identical to ``update.coincidence_counts`` (the counts are integer
     sums of {0, 1} products in f32, contracted in one bf16 MXU pass whatever
-    the ambient matmul precision) and to the count stage of the fused
-    ``pulse_update_pallas`` launch, so chunked pallas updates accumulate
-    counts that finalize to exactly the materialized fused result.
+    the ambient matmul precision), so chunked pallas updates accumulate
+    counts that finalize to exactly the materialized result.
     """
     m = streams_rows.shape[-1]
     n = streams_cols.shape[-1]

@@ -1,21 +1,18 @@
-"""Pallas TPU kernel: fused stochastic-pulse weight update (Eq. 1).
+"""Pallas TPU kernel: the update cycle's coincidence counts (Eq. 1).
 
 Given the signed pulse streams ``B (T, M_phys)`` (row drivers) and
-``A (T, N)`` (column drivers), one update cycle per device is
+``A (T, N)`` (column drivers), one update cycle's counts per device are
 
     net_ij   = sum_t B[t,i] A[t,j]           (MXU matmul #1)
     total_ij = sum_t |B[t,i]| |A[t,j]|       (MXU matmul #2)
     count_up = (total+net)/2,  count_dn = (total-net)/2
-    dw       = count_up*dw_up - count_dn*dw_dn
-               + ctoc * sqrt(count_up*dw_up^2 + count_dn*dw_dn^2) * xi_ij
-    w_new    = clip(w + dw, -bound, bound)
 
-The kernel fuses both stream matmuls with the per-device map application,
-cycle-to-cycle noise (on-chip counter-hash Gaussian, bit-matching
-``fastrng.normal``) and the conductance-bound clip — the unfused graph would
-round-trip four (M, N) tensors (net, total, dw, noise) through HBM.
+Both stream matmuls run in one launch; nothing round-trips HBM but the two
+(M, N) count tiles.  The device maps, cycle-to-cycle noise and bound clip
+stay digital, in the finalize every update path shares
+(``core.update.finalize_counts``).
 
-Both kernels contract the streams in one bf16 MXU pass with f32
+The kernel contracts the streams in one bf16 MXU pass with f32
 accumulation (``_accumulate_counts``).  That is exact: every entry is in
 {0, +-1}, so every product is exact in bf16, and an f32 sum of at most
 2**24 integers of magnitude 1 is exact in any order.
@@ -34,43 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.noisy_mvm import _mix, _normal_at
-
-
-def _make_kernel(nt, bm, bn, n_cols, ctoc, n_total, interpret):
-    def kernel(seed_ref, b_ref, a_ref, w_ref, up_ref, dn_ref, bound_ref,
-               out_ref, net_ref, tot_ref):
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        t = pl.program_id(2)
-        _accumulate_counts(t, b_ref, a_ref, net_ref, tot_ref, interpret)
-
-        @pl.when(t == nt - 1)
-        def _finalize():
-            net = net_ref[...]
-            tot = tot_ref[...]
-            count_up = 0.5 * (tot + net)
-            count_dn = 0.5 * (tot - net)
-            dw_up = up_ref[...]
-            dw_dn = dn_ref[...]
-            dw = count_up * dw_up - count_dn * dw_dn
-            if ctoc > 0.0:
-                rows = (i * bm + jax.lax.broadcasted_iota(
-                    jnp.uint32, (bm, bn), 0))
-                cols = (j * bn + jax.lax.broadcasted_iota(
-                    jnp.uint32, (bm, bn), 1))
-                e = rows * np.uint32(n_cols) + cols
-                xi = _normal_at(_mix(seed_ref[0, 0]), e, n_total)
-                var = count_up * dw_up * dw_up + count_dn * dw_dn * dw_dn
-                dw = dw + np.float32(ctoc) * jnp.sqrt(var) * xi
-            bound = bound_ref[...]
-            out_ref[...] = jnp.clip(w_ref[...] + dw, -bound, bound)
-
-    return kernel
 
 
 def _accumulate_counts(t, b_ref, a_ref, net_ref, tot_ref, interpret):
@@ -147,27 +109,14 @@ def counts_blocks(t: int, m: int, n: int):
     return _block(_round_up(t, 16)), _block(m), _block(n)
 
 
-def _stream_operands(streams_rows, streams_cols, tp, mp, np_):
-    """The kernels' stream operands: bf16 (exact on {0, +-1}), zero-padded
-    (padding fires no pulses)."""
-    t, m = streams_rows.shape
-    n = streams_cols.shape[1]
-    return (jnp.pad(streams_rows.astype(jnp.bfloat16),
-                    ((0, tp - t), (0, mp - m))),
-            jnp.pad(streams_cols.astype(jnp.bfloat16),
-                    ((0, tp - t), (0, np_ - n))))
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def pulse_counts_pallas(streams_rows: jax.Array, streams_cols: jax.Array, *,
                         interpret: bool = False, name: str = "pulse_counts"):
-    """Fused coincidence-count contraction only: the chunked-update entry.
+    """Coincidence counts of one update cycle, or of one chunk of it.
 
     The streaming update cycle accumulates per-chunk ``(count_up,
     count_dn)`` — integer-valued f32, so chunk sums are exact — and applies
-    maps/ctoc/clip once at the end (``core.update.finalize_counts``); this
-    kernel is the per-chunk contraction (both stream matmuls in one launch,
-    nothing round-trips HBM but the two (M, N) count tiles).
+    maps/ctoc/clip once at the end (``core.update.finalize_counts``).
 
     ``streams_rows`` (T, M_phys), ``streams_cols`` (T, N): every entry in
     {0, +-1}, any float dtype, and T <= 2**24, so that one bf16 MXU pass
@@ -184,10 +133,12 @@ def pulse_counts_pallas(streams_rows: jax.Array, streams_cols: jax.Array, *,
                          f"to {MAX_STREAM_SLOTS}")
     bt, bm, bn = counts_blocks(t, m, n)
     tp = _round_up(t, bt)
-    # Only the contracted T axis is zero-padded.  Edge blocks past M or N
+    # The operands are bf16 (exact on {0, +-1}).  Only the contracted T axis
+    # is zero-padded (padding fires no pulses).  Edge blocks past M or N
     # read whatever lies beyond the array; that reaches only count rows and
     # columns past it, which are never written back.
-    rp, cp = _stream_operands(streams_rows, streams_cols, tp, m, n)
+    rp, cp = (jnp.pad(s.astype(jnp.bfloat16), ((0, tp - t), (0, 0)))
+              for s in (streams_rows, streams_cols))
 
     up, dn = pl.pallas_call(
         _make_counts_kernel(tp // bt, interpret),
@@ -210,53 +161,3 @@ def pulse_counts_pallas(streams_rows: jax.Array, streams_cols: jax.Array, *,
         interpret=interpret,
     )(rp, cp)
     return up, dn
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("ctoc", "bm", "bn", "bt", "interpret", "name"))
-def pulse_update_pallas(w: jax.Array, dw_up: jax.Array, dw_dn: jax.Array,
-                        bound: jax.Array, streams_rows: jax.Array,
-                        streams_cols: jax.Array, seed: jax.Array, *,
-                        ctoc: float, bm: int = 128, bn: int = 128,
-                        bt: int = 128, interpret: bool = False,
-                        name: str = "pulse_update") -> jax.Array:
-    """Fused pulse update.  ``streams_rows`` (T, M_phys), ``streams_cols``
-    (T, N) signed {0, +-1}; returns the clipped new physical weights."""
-    m, n = w.shape
-    t = streams_rows.shape[0]
-    assert streams_rows.shape == (t, m) and streams_cols.shape == (t, n)
-
-    mp, np_, tp = _round_up(m, bm), _round_up(n, bn), _round_up(t, bt)
-    wp = jnp.pad(w, ((0, mp - m), (0, np_ - n)))
-    upp = jnp.pad(dw_up, ((0, mp - m), (0, np_ - n)))
-    dnp = jnp.pad(dw_dn, ((0, mp - m), (0, np_ - n)))
-    bp = jnp.pad(bound, ((0, mp - m), (0, np_ - n)))
-    rp, cp = _stream_operands(streams_rows, streams_cols, tp, mp, np_)
-
-    kern = _make_kernel(tp // bt, bm, bn, n, ctoc, m * n, interpret)
-
-    out = pl.pallas_call(
-        kern,
-        name=name,
-        grid=(mp // bm, np_ // bn, tp // bt),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, t: (0, 0)),     # seed
-            pl.BlockSpec((bt, bm), lambda i, j, t: (t, i)),   # row streams
-            pl.BlockSpec((bt, bn), lambda i, j, t: (t, j)),   # col streams
-            pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),   # w
-            pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),   # dw_up
-            pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),   # dw_dn
-            pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),   # bound
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), w.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(seed.reshape(1, 1).astype(jnp.uint32), rp, cp, wp, upp, dnp, bp)
-    return out[:m, :n]

@@ -29,9 +29,9 @@ stacks every pair and calls ``update.pulse_update`` once —
 across NM x fixed-latency BM x ``devices_per_weight`` x time-chunk sizes.
 
 With ``cfg.fuse_bwd_update`` each timestep's backward read + count
-contraction runs as ONE fused Pallas launch (``ops.bwd_update_mvm`` with
-the per-timestep ``row_offset``) — same counters, same counts, still one
-shared finalize per step.
+contraction runs as ONE fused Pallas launch
+(``tile.backward_update_counts`` with the per-timestep ``row_offset``) —
+same counters, same counts, still one shared finalize per step.
 
 Constraints (checked at trace time):
 
@@ -65,6 +65,7 @@ from repro.core import tile as tile_lib
 from repro.core import update as update_lib
 from repro.core.device import RPUConfig, sample_device_maps
 from repro.core.tile import TileState, replicate_delta
+from repro.kernels.bwd_update_mvm import bwd_update_eligible
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -212,7 +213,7 @@ def _check_cfg(cfg: RPUConfig) -> None:
             "scan-over-time analog cells require cfg.fast_rng: the "
             "counter-offset pulse streams are what make chunked updates "
             "bit-identical to the unrolled cycle")
-    if cfg.tile_grid is not None and tuple(cfg.tile_grid) != (1, 1):
+    if cfg.grid_routed:
         raise NotImplementedError(
             "recurrent cells are single-tile; tile_grid sharding of the "
             "temporal accumulation is not routed yet")
@@ -231,16 +232,6 @@ def _chunks(spec: CellSpec, t_total: int) -> Tuple[int, int]:
     return t_total // tc, tc
 
 
-def _fuse_temporal(cfg: RPUConfig, wx: Array, wh: Array) -> bool:
-    """Static routing: fused per-timestep backward+update launches for
-    BOTH tiles, else the separate-launch cycles for both (the oracle)."""
-    if not cfg.fuse_bwd_update:
-        return False
-    from repro.kernels.bwd_update_mvm import bwd_update_eligible
-    return (bwd_update_eligible(cfg, wx.shape)
-            and bwd_update_eligible(cfg, wh.shape))
-
-
 def tile_cycles(w_st: TileState, col_drv: Array, delta: Array,
                 k_read: Array, k_a: Array, k_b_upd: Array, row0: Array,
                 cfg: RPUConfig, lr_arr: Array, cx: Array, cd: Array,
@@ -254,14 +245,9 @@ def tile_cycles(w_st: TileState, col_drv: Array, delta: Array,
     implementation of the temporal-accumulation contract.
     """
     if fused:
-        from repro.kernels import ops as kops
-        g_rep = replicate_delta(delta, d, rows_phys=w_st.w.shape[0])
-        z, _sat, up, dn = kops.bwd_update_mvm(
-            w_st.w, col_drv, g_rep, k_read, k_a, k_b_upd, cfg, lr_arr,
+        return tile_lib.backward_update_counts(
+            w_st.w, col_drv, delta, k_read, k_a, k_b_upd, cfg, lr_arr,
             row_offset=row0)
-        if d > 1:
-            z = z / d
-        return z, up, dn
     z = tile_lib.tile_backward(w_st, delta, k_read, cfg)
     d_rep = replicate_delta(-delta, d, rows_phys=w_st.w.shape[0])
     up, dn = update_lib.stream_counts(
@@ -350,7 +336,10 @@ def _analog_scan_bwd(spec, cfg, saved, cts):
 
     wx_st = TileState(w=wx, maps=None, seed=sx)
     wh_st = TileState(w=wh, maps=None, seed=sh)
-    fused = _fuse_temporal(cfg, wx, wh)
+    # fused per-timestep launches for BOTH tiles, else the separate-launch
+    # cycles for both (the oracle)
+    fused = (bwd_update_eligible(cfg, wx.shape)
+             and bwd_update_eligible(cfg, wh.shape))
 
     def cycles(w_st, col_drv, delta, k_read, k_a, k_b_upd, t):
         row0 = (t * b).astype(jnp.uint32) if hasattr(t, "dtype") \

@@ -46,6 +46,7 @@ from repro.core import tile as tile_lib
 from repro.core import update as update_lib
 from repro.core.device import RPUConfig, sample_device_maps
 from repro.core.tile import TileState
+from repro.kernels.bwd_update_mvm import bwd_update_eligible
 from repro.recurrent.cell import _check_cfg, _split3, tile_cycles
 
 Array = jax.Array
@@ -61,7 +62,7 @@ class TemporalSpec:
 def temporal_eligible(cfg: RPUConfig) -> bool:
     """True when ``cfg`` supports streamed temporal accumulation."""
     return (not cfg.update_management and cfg.fast_rng
-            and (cfg.tile_grid is None or tuple(cfg.tile_grid) == (1, 1)))
+            and not cfg.grid_routed)
 
 
 def _chunks(spec: TemporalSpec, t_total: int) -> Tuple[int, int]:
@@ -78,13 +79,6 @@ def _aug(spec: TemporalSpec, x: Array) -> Array:
         return x
     ones = jnp.ones((*x.shape[:-1], 1), dtype=x.dtype)
     return jnp.concatenate([x, ones], axis=-1)
-
-
-def _fuse(cfg: RPUConfig, w: Array) -> bool:
-    if not cfg.fuse_bwd_update:
-        return False
-    from repro.kernels.bwd_update_mvm import bwd_update_eligible
-    return bwd_update_eligible(cfg, w.shape)
 
 
 # Per-step slices ride as scan INPUTS and each timestep compiles in its
@@ -142,7 +136,7 @@ def _temporal_bwd(spec, cfg, saved, g_ys):
     cx = cd = jnp.asarray(c_amp, dtype)   # UM gated off => constant gains
 
     st = TileState(w=w, maps=None, seed=seed)
-    fused = _fuse(cfg, w)
+    fused = bwd_update_eligible(cfg, w.shape)
 
     def step(carry, inp):
         up, dn = carry

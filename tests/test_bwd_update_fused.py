@@ -26,6 +26,7 @@ import pytest
 
 from repro.core import analog_linear as al
 from repro.core import conv_mapping as cm
+from repro.core import tile as tl
 from repro.core.device import RPUConfig
 from repro.core.tile import TileState
 from repro.kernels import ops as kops
@@ -193,9 +194,9 @@ def test_fused_wrapper_rejects_iterative_bm():
                                      bm_mode="iterative"))
     w = jnp.zeros((8, 12))
     with pytest.raises(ValueError, match="iterative"):
-        kops.bwd_update_mvm(w, jnp.zeros((4, 12)), jnp.zeros((4, 8)),
-                            jax.random.key(0), jax.random.key(1),
-                            jax.random.key(2), cfg, 0.01)
+        tl.backward_update_counts(w, jnp.zeros((4, 12)), jnp.zeros((4, 8)),
+                                  jax.random.key(0), jax.random.key(1),
+                                  jax.random.key(2), cfg, 0.01)
 
 
 # ---------------------------------------------------------------------------

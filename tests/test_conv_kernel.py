@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core import conv_mapping as cm
+from repro.core import management
 from repro.core import tile as tl
 from repro.core.device import RPUConfig
 from repro.kernels import conv_mvm
@@ -60,7 +61,8 @@ def test_conv_kernel_matches_reference(nm, bm, dpw, bias):
     use_nm = nm  # forward NM needs nm_forward
     nm_s = (cm._conv_nm_scale(xpad, geom) if use_nm
             else jnp.ones((geom.positions, 1), x.dtype))
-    y_k, sat_k = kops.conv_managed_mvm(st.w, xpad, geom, nm_s, key, cfg)
+    args = management.launch_args(cfg, key, backward=False, nm_s=nm_s)
+    y_k, sat_k = kops.conv_managed_mvm(st.w, xpad, geom, args, cfg)
     y_ref, sat_ref = _reference_read(cfg, st, x, geom, key)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_k), **TOL)
     np.testing.assert_array_equal(np.asarray(sat_ref), np.asarray(sat_k))
@@ -76,7 +78,8 @@ def test_conv_kernel_stride_dilation():
     key = jax.random.key(7)
     xpad = cm._pad_volume(x, geom)
     nm_s = jnp.ones((geom.positions, 1), x.dtype)
-    y_k, _ = kops.conv_managed_mvm(st.w, xpad, geom, nm_s, key, cfg)
+    args = management.launch_args(cfg, key, backward=False, nm_s=nm_s)
+    y_k, _ = kops.conv_managed_mvm(st.w, xpad, geom, args, cfg)
     y_ref, _ = _reference_read(cfg, st, x, geom, key)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_k), **TOL)
 

@@ -11,12 +11,11 @@ import numpy as np
 import pytest
 
 from repro.core.device import RPUConfig, sample_device_maps
+from repro.core import tile as tl
 from repro.core import update as update_lib
 from repro.kernels import ops as kops
-from repro.kernels import ref as kref
 from repro.kernels.noisy_mvm import noisy_mvm_pallas
-from repro.kernels.pulse_update import (pulse_counts_pallas,
-                                        pulse_update_pallas)
+from repro.kernels.pulse_update import pulse_counts_pallas
 from repro.utils import fastrng
 
 
@@ -43,7 +42,7 @@ def test_noisy_mvm_matches_reference(r, c, b, sigma, alpha, n_seg, tr):
         read_noise=sigma, out_bound=alpha,
         max_array_cols=10 ** 9 if tr else -(-c // n_seg),
         max_array_rows=-(-r // n_seg) if tr else 10 ** 9)
-    y_ref, sat_ref = kref.noisy_mvm_ref(w, x, key, cfg, transpose=tr)
+    y_ref, sat_ref = tl.analog_mvm_reference(w, x, key, cfg, transpose=tr)
     y_k, sat_blk = noisy_mvm_pallas(
         w, x, fastrng.key_to_seed(key), sigma=sigma, alpha=alpha,
         n_seg=n_seg, transpose=tr, interpret=True)
@@ -59,7 +58,7 @@ def test_noisy_mvm_dtypes(dtype):
     x = (jax.random.normal(jax.random.key(2), (32, 96)) * 0.5).astype(dtype)
     key = jax.random.key(9)
     cfg = RPUConfig(dtype=dtype)
-    y_ref, _ = kref.noisy_mvm_ref(w.astype(dtype), x, key, cfg)
+    y_ref, _ = tl.analog_mvm_reference(w.astype(dtype), x, key, cfg)
     y_k, _ = noisy_mvm_pallas(
         w.astype(dtype), x, fastrng.key_to_seed(key),
         sigma=cfg.read_noise, alpha=cfg.out_bound, interpret=True)

@@ -20,7 +20,6 @@ import pytest
 from repro.core import tile as tl
 from repro.core.device import RPUConfig
 from repro.kernels import ops as kops
-from repro.kernels import ref as kref
 
 TOL = dict(rtol=2e-5, atol=2e-5)
 
@@ -69,11 +68,11 @@ def test_fused_managed_read_matches_reference(r, c, b, n_seg, tr, d, nm, bm):
     w, x = _data(r, c, b, tr)
     key = jax.random.key(hash((r, c, b, n_seg, tr, d, nm, bm)) % (2 ** 31))
 
-    y_ref, sat_ref = kref.managed_mvm_ref(w, x, key, cfg, transpose=tr,
-                                          backward=tr)
+    y_ref, sat_ref = tl.managed_mvm_reference(w, x, key, cfg, transpose=tr,
+                                              backward=tr)
     if not tr and d > 1:
         y_ref = tl._replica_mean(y_ref, d)
-    y_k, sat_k = kops.managed_mvm(w, x, key, cfg, transpose=tr, backward=tr)
+    y_k, sat_k = tl.managed_mvm(w, x, key, cfg, transpose=tr, backward=tr)
 
     assert y_k.shape == y_ref.shape
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_k), **TOL)
@@ -137,7 +136,7 @@ def test_fused_residual_saturation_semantics():
     w = jnp.eye(4)
     x = jnp.stack([jnp.full((4,), 100.0), jnp.full((4,), 1000.0),
                    jnp.full((4,), 1.0)])
-    y, sat = kops.managed_mvm(w, x, jax.random.key(3), cfg)
+    y, sat = tl.managed_mvm(w, x, jax.random.key(3), cfg)
     np.testing.assert_array_equal(np.asarray(sat), [False, True, False])
     np.testing.assert_allclose(np.asarray(y[0]), 100.0, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(y[1]), 16.0 * 12.0, rtol=1e-5)
@@ -149,7 +148,7 @@ def test_fused_managed_read_batch_shapes():
                     bound_management=True, bm_mode="two_phase")
     w = jax.random.normal(jax.random.key(1), (40, 30)) * 0.2
     x = jax.random.normal(jax.random.key(2), (4, 7, 30))
-    y, sat = kops.managed_mvm(w, x, jax.random.key(5), cfg)
+    y, sat = tl.managed_mvm(w, x, jax.random.key(5), cfg)
     assert y.shape == (4, 7, 40)
     assert sat.shape == (4, 7)
 

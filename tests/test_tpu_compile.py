@@ -88,10 +88,10 @@ _MANAGED = dataclasses.replace(dev.rpu_nm_bm_um_bl1(), bm_mode="two_phase",
 ])
 def test_managed_read_compiles(one_chip, compiled_kernels, m, n, d_avg,
                                transpose):
-    from repro.kernels import ops
+    from repro.core import tile
     cfg = dataclasses.replace(_MANAGED, devices_per_weight=d_avg)
-    c = _compile(lambda w, x, k: ops.managed_mvm(w, x, k, cfg,
-                                                 transpose=transpose),
+    c = _compile(lambda w, x, k: tile.managed_mvm(w, x, k, cfg,
+                                                  transpose=transpose),
                  _sds((m, n), one_chip),
                  _sds((BATCH, m if transpose else n), one_chip),
                  _key(one_chip))
@@ -112,10 +112,10 @@ def test_managed_read_compiles_from_many_rows(one_chip, compiled_kernels, m,
     """With more than one 128-row block the output block's buffer pair is
     live beside the epilogue (``managed_read_vmem``): the LM train step's
     reads compile, each to one kernel."""
-    from repro.kernels import ops
-    c = _compile(lambda w, x, k: ops.managed_mvm(w, x, k, _MANAGED,
-                                                 transpose=transpose,
-                                                 backward=transpose),
+    from repro.core import tile
+    c = _compile(lambda w, x, k: tile.managed_mvm(w, x, k, _MANAGED,
+                                                  transpose=transpose,
+                                                  backward=transpose),
                  _sds((m, n), one_chip),
                  _sds((rows, m if transpose else n), one_chip),
                  _key(one_chip))
@@ -149,9 +149,9 @@ def test_gate_maxima_are_the_compiled_shapes(kind, admitted, refused):
 def test_managed_read_too_wide_raises(compiled_kernels):
     """A read whose blocks cannot fit VMEM (a 102400-wide unembed) raises
     by name instead of compiling or taking another path."""
-    from repro.kernels import ops
+    from repro.core import tile
     with pytest.raises(ValueError, match="VMEM"):
-        jax.eval_shape(lambda w, x, k: ops.managed_mvm(w, x, k, _MANAGED),
+        jax.eval_shape(lambda w, x, k: tile.managed_mvm(w, x, k, _MANAGED),
                        jax.ShapeDtypeStruct((102400, 4096), jnp.float32),
                        jax.ShapeDtypeStruct((BATCH, 4096), jnp.float32),
                        jax.eval_shape(lambda: jax.random.key(0)))
