@@ -27,8 +27,16 @@ allclose at matmul-reassociation tolerance only.
 Layout: grid ``(batch/bm, K/bk)`` with the contraction axis innermost
 ("arbitrary"); the full (replica-padded) physical output dimension lives in
 one VMEM block so the per-vector saturation flag — which gates the select
-across *all* output channels — never leaves the chip.  Weights are padded
-per replica block to a lane multiple so the in-kernel #_d average is a few
+across *all* output channels — never leaves the chip.  The row block follows
+the operand (:func:`managed_read_blocks`: the largest of 512, 256 and 128
+rows that pads little and fits VMEM); ``bk`` is 128, so each output
+element's contraction runs in the same 128-deep chunks whatever the rows.
+The epilogue runs in static column slabs of :data:`SLAB` lanes: at a segment
+boundary each slab draws both reads' noise, clips and ORs its saturation
+flags (a single-segment read stores read 1 over the raw product); once the
+whole row's flags are known, the select and the #_d average run slab by
+slab.  Only the accumulators are full width.  Weights are padded per
+replica block to a lane multiple so the in-kernel #_d average is a few
 static slices.  The scoped-VMEM limit is set from those block shapes
 (:func:`managed_read_vmem`); an output too wide for one core's VMEM (a
 100k-row unembed) raises rather than compiles.  The iterative-BM
@@ -68,8 +76,9 @@ from repro.kernels.noisy_mvm import _mix, _normal_at
 VMEM_CAP = 100 * 2 ** 20
 #: Mosaic's default scoped-VMEM limit on v5e — never ask for less.
 _SCOPED_DEFAULT = 16 * 2 ** 20
-#: live (rows, out) f32 temporaries of the noise + select epilogue (measured
-#: by compiling for v5e: about three at 4096 and 11008 output columns)
+#: live (rows, out) f32 temporaries of a whole-width noise + select epilogue,
+#: as the conv and fused backward kernels run it (measured by compiling for
+#: v5e: about three at 4096 and 11008 output columns)
 EPILOGUE_TEMPS = 4
 _SLACK = 2 * 2 ** 20
 
@@ -112,22 +121,57 @@ def vmem_limit(nbytes: int, name: str) -> int:
     return max(nbytes + _SLACK, _SCOPED_DEFAULT)
 
 
-def managed_read_vmem(bm: int, bk: int, outp: int, out_f_p: int) -> int:
+#: lanes of one column slab of the managed read's epilogue
+SLAB = 512
+#: live (rows, SLAB) f32 temporaries of one slab of that epilogue
+SLAB_TEMPS = 10
+
+
+def for_slabs(width: int, body, carry=()):
+    """``carry = body(start, lanes, carry)`` over the column slabs of
+    ``width`` lanes: a loop over the :data:`SLAB`-wide ones (``start``
+    traced, a multiple of SLAB), then the narrower tail (``start``
+    static).  One slab body is compiled, whatever the width."""
+    n_full = width // SLAB
+    if n_full:
+        carry = jax.lax.fori_loop(
+            0, n_full,
+            lambda j, c: body(pl.multiple_of(j * SLAB, SLAB), SLAB, c),
+            carry)
+    if width % SLAB:
+        carry = body(n_full * SLAB, width % SLAB, carry)
+    return carry
+
+
+def managed_read_vmem(bm: int, bk: int, outp: int, out_f_p: int,
+                      n_seg: int) -> int:
     """Working-set bytes of one :func:`managed_mvm_pallas` launch.
 
-    Compiled for v5e at 2048, 4096 and 11008 output columns, a launch holds
-    10 (rows, out) f32 blocks with one row block and 12 with more (256
-    rows and up): with one row block the output is written once and needs
-    no buffer pair of its own.  The count here is 12 (the weight and output
-    pairs, three accumulators, and an epilogue of one temporary more than
-    :data:`EPILOGUE_TEMPS`), so it holds at every row count; at 11 it fell
-    3.5 MiB short of the 65.1 MiB an 11008-column read from 256 rows
-    needs."""
+    Full width are only the pipelined output pair and the accumulators:
+    the raw product (which holds read 1 when the read has one segment),
+    read 2, and read 1's own when the segments sum.  The epilogue's
+    temporaries are slab-sized (:data:`SLAB_TEMPS`, measured by compiling
+    for v5e)."""
     return (2 * (tile_bytes(bm, bk) + tile_bytes(outp, bk)      # x, w
                  + tile_bytes(bm, 1)                            # nm scale
                  + tile_bytes(bm, out_f_p) + tile_bytes(bm, 1))  # y, sat
-            + 3 * tile_bytes(bm, outp) + 2 * tile_bytes(bm, 1)  # scratch
-            + (EPILOGUE_TEMPS + 1) * tile_bytes(bm, outp))      # epilogue
+            + (2 if n_seg == 1 else 3) * tile_bytes(bm, outp)   # scratch
+            + 2 * tile_bytes(bm, 1)
+            + SLAB_TEMPS * tile_bytes(bm, min(SLAB, outp)))     # epilogue
+
+
+def managed_read_blocks(rows: int, outp: int, n_seg: int) -> int:
+    """Row block ``bm`` of a managed read of ``rows`` vectors into ``outp``
+    replica-padded output lanes over ``n_seg`` segments: the largest of
+    512, 256 and 128 that pads the 128-padded row count by at most 3% and
+    whose launch fits VMEM.  128 when none does (a read too wide even for
+    that raises in :func:`vmem_limit`)."""
+    rows_p = pad_to(rows, 128)
+    for bm in (512, 256):
+        if (pad_to(rows_p, bm) <= 1.03 * rows_p
+                and fits_vmem(managed_read_vmem(bm, 128, outp, outp, n_seg))):
+            return bm
+    return 128
 
 
 # ---------------------------------------------------------------------------
@@ -140,14 +184,16 @@ def managed_read_vmem(bm: int, bk: int, outp: int, out_f_p: int) -> int:
 # counters, same clip/select/average expression order).
 # ---------------------------------------------------------------------------
 
-def replica_cols(bm: int, outp: int, out_f: int, out_f_p: int):
-    """Physical output-channel index of each replica-padded column.
+def replica_cols(bm: int, outp: int, out_f: int, out_f_p: int,
+                 col0: int = 0):
+    """Physical output-channel index of each replica-padded column of the
+    ``outp`` lanes from column ``col0`` on.
 
     Returns ``(o, valid)``: ``o`` maps padded column -> physical channel
     (for the noise counter), ``valid`` masks the per-replica lane padding
     out of the saturation reduction.
     """
-    cols = jax.lax.broadcasted_iota(jnp.uint32, (bm, outp), 1)
+    cols = jax.lax.broadcasted_iota(jnp.uint32, (bm, outp), 1) + col0
     rep = cols // np.uint32(out_f_p)
     within = cols - rep * np.uint32(out_f_p)
     o = rep * np.uint32(out_f) + within
@@ -173,18 +219,22 @@ def read_segment(v, seed, e, n_total: int, valid, sigma: float,
     return v, sat
 
 
+def select_read(acc1, acc2, sat1, s, *, two_phase: bool,
+                retry_scale: float):
+    """Two-phase select-on-saturation and digital re-scale, per column."""
+    if two_phase:
+        y2 = acc2 * np.float32(retry_scale)
+        return jnp.where(sat1 > 0, y2, acc1) * s
+    return acc1 * s
+
+
 def select_and_average(acc1, acc2, sat1, sat2, s, *, two_phase: bool,
                        retry_scale: float, d_avg: int, out_f_p: int):
     """Two-phase select-on-saturation, digital re-scale and #_d replica
     average — the managed read's epilogue.  Returns ``(y, residual)``."""
-    if two_phase:
-        sel = sat1 > 0                                      # (rows, 1)
-        y2 = acc2 * np.float32(retry_scale)
-        y = jnp.where(sel, y2, acc1) * s
-        residual = sat1 & sat2
-    else:
-        y = acc1 * s
-        residual = sat1
+    y = select_read(acc1, acc2, sat1, s, two_phase=two_phase,
+                    retry_scale=retry_scale)
+    residual = sat1 & sat2 if two_phase else sat1
     if d_avg > 1:
         acc = y[:, 0:out_f_p]
         for rblk in range(1, d_avg):
@@ -194,19 +244,22 @@ def select_and_average(acc1, acc2, sat1, sat2, s, *, two_phase: bool,
 
 
 def _kernel(seeds_ref, off_ref, nm_ref, x_ref, w_ref, y_ref, sat_ref,
-            seg_ref, acc1_ref, acc2_ref, sat1_ref, sat2_ref, *,
-            nk: int, steps_per_seg: int, n_seg: int, sigma: float,
-            alpha: float, bm: int, outp: int, out_f: int, out_f_p: int,
-            d_avg: int, out_phys: int, batch: int, transpose: bool,
-            two_phase: bool, retry_scale: float):
+            seg_ref, *scratch, nk: int, steps_per_seg: int, n_seg: int,
+            sigma: float, alpha: float, bm: int, outp: int, out_f: int,
+            out_f_p: int, d_avg: int, out_phys: int, batch: int,
+            transpose: bool, two_phase: bool, retry_scale: float):
     i = pl.program_id(0)
     k = pl.program_id(1)
+    # one segment: read 1 is stored over the raw product it is drawn from
+    acc1_ref, acc2_ref, sat1_ref, sat2_ref = (
+        (seg_ref,) + scratch if n_seg == 1 else scratch)
 
     @pl.when(k == 0)
     def _init():
         seg_ref[...] = jnp.zeros_like(seg_ref)
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
+        if n_seg > 1:
+            acc1_ref[...] = jnp.zeros_like(acc1_ref)
+            acc2_ref[...] = jnp.zeros_like(acc2_ref)
         sat1_ref[...] = jnp.zeros_like(sat1_ref)
         sat2_ref[...] = jnp.zeros_like(sat2_ref)
 
@@ -223,41 +276,69 @@ def _kernel(seeds_ref, off_ref, nm_ref, x_ref, w_ref, y_ref, sat_ref,
             xb, wb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    def store(ref, cols, v):
+        if n_seg == 1:
+            ref[:, cols] = v
+        else:
+            ref[:, cols] += v
+
     @pl.when((k + 1) % steps_per_seg == 0)
     def _segment_boundary():
         si = (k // steps_per_seg).astype(jnp.uint32)
         s = nm_ref[...]                       # (bm, 1) combined digital scale
-        v1 = seg_ref[...] / s                 # read 1: W (x / s)
-
-        # physical column index of each padded column (replica-padded layout)
-        o, valid = replica_cols(bm, outp, out_f, out_f_p)
         rows = (off_ref[0, 0] + i * bm
-                + jax.lax.broadcasted_iota(jnp.uint32, (bm, outp), 0))
+                + jax.lax.broadcasted_iota(jnp.uint32, (bm, 1), 0))
         # flat counter e = (b * n_seg + si) * out_phys + o  (reference layout)
-        e = (rows * np.uint32(n_seg) + si) * np.uint32(out_phys) + o
+        e_row = (rows * np.uint32(n_seg) + si) * np.uint32(out_phys)
         n_total = (batch * n_seg * out_phys) & 0xFFFFFFFF
 
-        v_read, sat = read_segment(v1, seeds_ref[0, 0], e, n_total, valid,
-                                   sigma, alpha)
-        sat1_ref[...] |= sat
-        acc1_ref[...] += v_read
-        if two_phase:
-            # read 2: W (x / (retry_scale * s)) — same MXU product, rescaled
-            v_read, sat = read_segment(
-                v1 / np.float32(retry_scale), seeds_ref[0, 1], e, n_total,
-                valid, sigma, alpha)
-            sat2_ref[...] |= sat
-            acc2_ref[...] += v_read
-        seg_ref[...] = jnp.zeros_like(seg_ref)
+        def read_slab(c0, lanes, sats):
+            cols = pl.ds(c0, lanes)
+            v1 = seg_ref[:, cols] / s         # read 1: W (x / s)
+            # physical column index of each padded column (replica layout)
+            o, valid = replica_cols(bm, lanes, out_f, out_f_p,
+                                    col0=jnp.uint32(c0))
+            e = e_row + o
+            v_read, sat1 = read_segment(v1, seeds_ref[0, 0], e, n_total,
+                                        valid, sigma, alpha)
+            store(acc1_ref, cols, v_read)
+            sat2 = sats[1]
+            if two_phase:
+                # read 2: W (x / (retry_scale * s)) — same product, rescaled
+                v_read, sat = read_segment(
+                    v1 / np.float32(retry_scale), seeds_ref[0, 1], e,
+                    n_total, valid, sigma, alpha)
+                store(acc2_ref, cols, v_read)
+                sat2 = sat2 | sat
+            if n_seg > 1:
+                seg_ref[:, cols] = jnp.zeros_like(v1)
+            return sats[0] | sat1, sat2
+
+        sat1, sat2 = for_slabs(outp, read_slab, (sat1_ref[...],
+                                                  sat2_ref[...]))
+        sat1_ref[...] = sat1
+        sat2_ref[...] = sat2
 
     @pl.when(k == nk - 1)
     def _finalize():
-        y, residual = select_and_average(
-            acc1_ref[...], acc2_ref[...], sat1_ref[...], sat2_ref[...],
-            nm_ref[...], two_phase=two_phase, retry_scale=retry_scale,
-            d_avg=d_avg, out_f_p=out_f_p)
-        y_ref[...] = y.astype(y_ref.dtype)
-        sat_ref[...] = residual
+        s, sat1 = nm_ref[...], sat1_ref[...]
+
+        def output_slab(c0, lanes, carry):
+            # the #_d average of select_and_average, one slab at a time
+            y = None
+            for rblk in range(d_avg):
+                cols = pl.ds(c0 + rblk * out_f_p, lanes)
+                yr = select_read(
+                    acc1_ref[:, cols], acc2_ref[:, cols] if two_phase else None,
+                    sat1, s, two_phase=two_phase, retry_scale=retry_scale)
+                y = yr if y is None else y + yr
+            if d_avg > 1:
+                y = y / np.float32(d_avg)
+            y_ref[:, pl.ds(c0, lanes)] = y.astype(y_ref.dtype)
+            return carry
+
+        for_slabs(out_f_p, output_slab)
+        sat_ref[...] = sat1 & sat2_ref[...] if two_phase else sat1
 
 
 @functools.partial(
@@ -270,7 +351,7 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
                        n_seg: int = 1, transpose: bool = False,
                        two_phase: bool = False, retry_scale: float = 16.0,
                        d_avg: int = 1, row_offset=None,
-                       total_rows: int = None, bm: int = 128, bk: int = 128,
+                       total_rows: int = None, bm: int = None, bk: int = 128,
                        interpret: bool = False, name: str = "managed_read"
                        ) -> Tuple[jax.Array, jax.Array]:
     """Fused managed analog read (NM scale + two-phase BM + replica average).
@@ -289,6 +370,7 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
         rows ``[row_offset, row_offset + B)`` of a logical batch of
         ``total_rows`` vectors and draws that batch's noise counters
         (``row_offset`` may be traced; ``total_rows`` is static).
+      bm: rows a block; None (the rule) takes :func:`managed_read_blocks`.
 
     Returns:
       y (B, out_f) replica-averaged managed read, and residual saturation
@@ -315,6 +397,8 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
     seg_len = -(-k_dim // n_seg)
     seg_len_p = pad_to(seg_len, bk)
     kp = n_seg * seg_len_p
+    if bm is None:
+        bm = managed_read_blocks(b, outp, n_seg)
     bp = pad_to(b, bm)
 
     def pad_contraction(a, axis):
@@ -383,7 +467,8 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, outp), jnp.float32),   # segment accumulator
-            pltpu.VMEM((bm, outp), jnp.float32),   # read-1 accumulator
+            *([pltpu.VMEM((bm, outp), jnp.float32)]  # read-1 accumulator
+              if n_seg > 1 else []),
             pltpu.VMEM((bm, outp), jnp.float32),   # read-2 accumulator
             pltpu.VMEM((bm, 1), jnp.int32),        # read-1 saturation
             pltpu.VMEM((bm, 1), jnp.int32),        # read-2 saturation
@@ -391,7 +476,7 @@ def managed_mvm_pallas(w: jax.Array, x2d: jax.Array, nm_s: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(
-                managed_read_vmem(bm, bk, outp, out_f_p), name)),
+                managed_read_vmem(bm, bk, outp, out_f_p, n_seg), name)),
         interpret=interpret,
     )(seeds.reshape(1, 2).astype(jnp.uint32), rowoff.reshape(1, 1), nm_pad,
       xpad, wpad)

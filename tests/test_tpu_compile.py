@@ -84,7 +84,8 @@ _MANAGED = dataclasses.replace(dev.rpu_nm_bm_um_bl1(), bm_mode="two_phase",
     (128, 513, 1, True),        # LeNet W3 transpose read
     (13 * 32, 401, 13, False),  # LeNet K2 (13 devices) on the gather path
     (11008, 4096, 1, False),    # deepseek_7b MLP up/gate projection
-    (16640, 4096, 1, False),    # the widest output the VMEM gate admits
+    (32384, 4096, 1, False),    # the widest output the VMEM gate admits
+    (27776, 8192, 1, False),    # ... over two array segments
 ])
 def test_managed_read_compiles(one_chip, compiled_kernels, m, n, d_avg,
                                transpose):
@@ -105,13 +106,14 @@ def test_managed_read_compiles(one_chip, compiled_kernels, m, n, d_avg,
     (4096, 4096, 8192, False),
     (11008, 4096, 8192, True),   # wi/wg transpose read, 3 array segments
     (4096, 11008, 8192, False),  # wo forward read, 3 array segments
-    (16640, 4096, 256, False),   # the widest output the VMEM gate admits
+    (32384, 4096, 256, False),   # the widest output the VMEM gate admits
 ])
 def test_managed_read_compiles_from_many_rows(one_chip, compiled_kernels, m,
                                               n, rows, transpose):
-    """With more than one 128-row block the output block's buffer pair is
-    live beside the epilogue (``managed_read_vmem``): the LM train step's
-    reads compile, each to one kernel."""
+    """With more than one row block the output block's buffer pair is live
+    beside the accumulators (``managed_read_vmem``): the LM train step's
+    reads compile with the blocks ``managed_read_blocks`` chooses (512
+    rows at 4096 wide, 256 at 11008), each to one kernel."""
     from repro.core import tile
     c = _compile(lambda w, x, k: tile.managed_mvm(w, x, k, _MANAGED,
                                                   transpose=transpose,
@@ -123,7 +125,8 @@ def test_managed_read_compiles_from_many_rows(one_chip, compiled_kernels, m,
 
 
 @pytest.mark.parametrize("kind, admitted, refused", [
-    ("managed_read", 16640, 16768),          # output columns
+    ("managed_read", 32384, 32512),          # output columns
+    ("managed_read_segmented", 27776, 27904),
     ("bwd_update", 1792, 1920),              # square tile side
     ("managed_read_conv", 17152, 17280),     # K2-geometry output channels
 ])
@@ -137,7 +140,10 @@ def test_gate_maxima_are_the_compiled_shapes(kind, admitted, refused):
     k2 = cm.conv_geometry((BATCH, 12, 12, 16), 5)
     fits = {
         "managed_read": lambda o: mm.fits_vmem(mm.managed_read_vmem(
-            128, 128, mm.pad_to(o, 128), mm.pad_to(o, 128))),
+            128, 128, mm.pad_to(o, 128), mm.pad_to(o, 128), 1)),
+        "managed_read_segmented": lambda o: mm.fits_vmem(
+            mm.managed_read_vmem(128, 128, mm.pad_to(o, 128),
+                                 mm.pad_to(o, 128), 3)),
         "bwd_update": lambda n: mm.fits_vmem(bu._dense_vmem(
             128, 128, mm.pad_to(n, 128), mm.pad_to(n, 128))),
         "managed_read_conv": lambda c: mm.fits_vmem(
